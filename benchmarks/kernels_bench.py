@@ -94,9 +94,9 @@ def lz4_encode_path(smoke: bool = False):
     captured = []
     orig = codec._lz4_slab_streams
 
-    def spy(slab, buf, starts, ends, force=None):
+    def spy(buf, starts, ends, force=None):
         captured.append((np.array(buf), np.array(starts), np.array(ends)))
-        return orig(slab, buf, starts, ends, force=force)
+        return orig(buf, starts, ends, force=force)
 
     codec._lz4_slab_streams = spy
     try:
@@ -115,7 +115,7 @@ def lz4_encode_path(smoke: bool = False):
         # the production kernel path end to end: gap compaction + match
         # kernel + ragged emit (klz4 imported above pins availability)
         assert klz4 is not None
-        return [codec._lz4_slab_streams(buf, buf, s, e)
+        return [codec._lz4_slab_streams(buf, s, e)
                 for buf, s, e in captured]
 
     def run_scalar():

@@ -13,6 +13,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import ROWS, dump_json
 
 MODULES = [
@@ -39,6 +41,7 @@ def main() -> None:
                          "unset = CSV only)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     print("table,name,value,unit,note")
     failures = []

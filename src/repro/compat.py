@@ -1,24 +1,15 @@
-"""Version shims for the installed jax.
+"""``shard_map`` with the keyword this codebase uses.
 
-The repo targets current jax but must import (and run its CPU tests) on
-older releases: ``shard_map`` moved from ``jax.experimental`` to the top
-level, and its replication-check kwarg was renamed ``check_rep`` →
-``check_vma`` along the way.
+Call sites pass ``check_vma``; it defaults to off here, as the model's
+collectives are written out by hand.
 """
 
 from __future__ import annotations
 
-try:  # jax ≥ 0.6
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the replication-check kwarg of either era."""
+    """``jax.shard_map`` with the replication check off by default."""
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+                      check_vma=check_vma)

@@ -392,31 +392,22 @@ def _lz4_compress_slab(buf: np.ndarray, chunks: Sequence[bytes]) -> List[bytes]:
     return outs
 
 
-_KERNEL_LZ4 = None       # lazy: module, or False when kernels are unavailable
-
-
 def _lz4_kernel():
-    """The ``kernels.lz4`` match engine, or ``None`` (missing runtime).
+    """The ``kernels.lz4`` match engine.
 
     The kernel and this module each carry the LZ4 policy constants; a
     drift would silently break kernel-vs-oracle byte identity, so it is
-    asserted once at first dispatch.
+    asserted at dispatch.
     """
-    global _KERNEL_LZ4
-    if _KERNEL_LZ4 is None:
-        try:
-            from ..kernels import lz4 as _k
+    from ..kernels import lz4 as _k
 
-            if (_k.HASH_LOG, _k.MIN_MATCH, _k.MFLIMIT, _k.LAST_LITERALS,
-                    _k.RUN_STRIDE) != (_HASH_LOG, _MIN_MATCH, _MFLIMIT,
-                                       _LAST_LITERALS, _RUN_STRIDE):
-                raise RuntimeError(
-                    "kernels.lz4 match-policy constants diverged from "
-                    "core.codec's scalar reference")
-            _KERNEL_LZ4 = _k
-        except ImportError:  # pragma: no cover - stripped install
-            _KERNEL_LZ4 = False
-    return _KERNEL_LZ4 or None
+    if (_k.HASH_LOG, _k.MIN_MATCH, _k.MFLIMIT, _k.LAST_LITERALS,
+            _k.RUN_STRIDE) != (_HASH_LOG, _MIN_MATCH, _MFLIMIT,
+                               _LAST_LITERALS, _RUN_STRIDE):
+        raise RuntimeError(
+            "kernels.lz4 match-policy constants diverged from "
+            "core.codec's scalar reference")
+    return _k
 
 
 def _scalar_lz4_forced() -> bool:
@@ -530,18 +521,14 @@ def lz4_emit_events(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
     return [blob[so[s]: so[s + 1]] for s in range(S)]
 
 
-def _lz4_slab_streams(slab, buf: np.ndarray, starts: np.ndarray,
+def _lz4_slab_streams(buf: np.ndarray, starts: np.ndarray,
                       ends: np.ndarray,
                       force: Optional[str] = None) -> List[bytes]:
-    """LZ4-compress the addressed streams of a slab, kernel path first.
-
-    ``slab`` may be a device array (handed straight to the match kernel —
-    no host round trip); ``buf`` is its host uint8 view for the emit.
-    Falls back to the PR 3 fused slab encoder when the kernel package is
-    unavailable or ``TRACE_SCALAR_LZ4=1`` pins the oracle.
+    """LZ4-compress the addressed streams of a uint8 slab, kernel path
+    first.  ``TRACE_SCALAR_LZ4=1`` pins the PR 3 fused slab encoder
+    instead (the parity oracle).
     """
-    kern = None if _scalar_lz4_forced() else _lz4_kernel()
-    if kern is None:
+    if _scalar_lz4_forced():
         chunks = [buf[s:e].tobytes() for s, e in zip(starts, ends)]
         return _lz4_compress_slab(
             np.frombuffer(b"".join(chunks), dtype=np.uint8), chunks)
@@ -550,17 +537,16 @@ def _lz4_slab_streams(slab, buf: np.ndarray, starts: np.ndarray,
     gapped = bool(starts.size) and (
         int(starts[0]) != 0 or int(ends[-1]) != buf.size
         or bool((starts[1:] != ends[:-1]).any()))
-    if gapped and isinstance(slab, np.ndarray):
+    if gapped:
         # bypassed streams leave gaps in the slab, and the match
-        # kernel's prep passes scale with every slab byte — compact a
-        # host slab down to the covered ranges (device slabs stay put:
-        # a copy there would cost the round trip this path avoids)
+        # kernel's prep passes scale with every slab byte — compact it
+        # down to the covered ranges
         sizes = ends - starts
         buf = np.concatenate([buf[a:b] for a, b in zip(starts, ends)])
         ends = np.cumsum(sizes)
         starts = ends - sizes
-        slab = buf
-    pos, dist, mlen = kern.match_events_slab(slab, starts, ends, force=force)
+    pos, dist, mlen = _lz4_kernel().match_events_slab(buf, starts, ends,
+                                                      force=force)
     return lz4_emit_events(buf, starts, ends, pos, dist, mlen)
 
 
@@ -581,7 +567,7 @@ def lz4_compress_batch(chunks: Sequence[bytes],
     ends = np.cumsum(sizes)
     starts = ends - sizes
     buf = np.frombuffer(b"".join(chunks), dtype=np.uint8)
-    return _lz4_slab_streams(buf, buf, starts, ends, force=force)
+    return _lz4_slab_streams(buf, starts, ends, force=force)
 
 
 def lz4_decompress(comp: bytes, max_out: int | None = None) -> bytes:
@@ -900,11 +886,10 @@ def compress_slab(slab, starts: Sequence[int], ends: Sequence[int],
                   force: Optional[str] = None) -> Tuple[List[bytes], List[int]]:
     """:func:`compress_batch` over streams addressed INSIDE a flat slab.
 
-    ``slab`` is a flat uint8 buffer — numpy, or a device array straight
-    from ``pack_planes_slab`` (the match kernel then consumes it without
-    a device→host→device round trip; only the emit reads a host view).
-    ``starts[i]:ends[i]`` bounds stream ``i``.  Byte-identical payloads
-    and flags to ``compress_batch([slab[s:e] ...], codec)``, but raw /
+    ``slab`` is a flat uint8 buffer, e.g. the ravelled planes of
+    ``pack_planes_slab``; ``starts[i]:ends[i]`` bounds stream ``i``.
+    Byte-identical payloads and flags to
+    ``compress_batch([slab[s:e] ...], codec)``, but raw /
     bypassed payloads are sliced from the slab and LZ4 streams go to the
     kernel as (start, end) bounds — no per-stream bytes are materialized
     before the bypass decision.
@@ -925,7 +910,7 @@ def compress_slab(slab, starts: Sequence[int], ends: Sequence[int],
     if todo:
         tsel = np.asarray(todo, dtype=np.int64)
         if name == "lz4":
-            comps = _lz4_slab_streams(slab, buf, starts[tsel], ends[tsel],
+            comps = _lz4_slab_streams(buf, starts[tsel], ends[tsel],
                                       force=force)
         elif name == "zstd":
             comps = zstd_compress_batch(

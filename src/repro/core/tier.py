@@ -848,22 +848,6 @@ class WordLayout(Layout):
         return _split_like(flat, outs)
 
 
-def _pack_slab(flat_u16: np.ndarray) -> np.ndarray:
-    """Pack a flat uint16 slab to (16, n//8) planes — pallas kernel when an
-    accelerator backend is up, numpy otherwise (lazy one-time dispatch)."""
-    global _PACK_SLAB
-    if _PACK_SLAB is None:
-        try:
-            from ..kernels.bitplane import pack_planes_slab
-            _PACK_SLAB = pack_planes_slab
-        except ImportError:  # pragma: no cover - kernels unavailable
-            _PACK_SLAB = lambda flat: pack_planes(flat)
-    return _PACK_SLAB(flat_u16)
-
-
-_PACK_SLAB = None
-
-
 class BitplaneLayout(Layout):
     """TRACE bit-plane substrate; plane-aligned fetch, vectorized batches."""
 
@@ -911,13 +895,13 @@ class BitplaneLayout(Layout):
         concatenate cleanly: packing the concatenation and slicing per
         block is byte-identical to packing each block alone.  The packed
         plane matrix is handed to the codec as a flat slab with (start,
-        end) stream bounds — no per-stream bytes are materialized, and
-        on accelerator backends the match kernel consumes the packed
-        planes without a device→host→device round trip.
+        end) stream bounds — no per-stream bytes are materialized.
         """
+        from ..kernels.bitplane import pack_planes_slab
+
         sizes = [c.size for c in chunks]
-        planes = _pack_slab(np.concatenate(chunks) if len(chunks) > 1
-                            else chunks[0].ravel())
+        planes = pack_planes_slab(np.concatenate(chunks) if len(chunks) > 1
+                                  else chunks[0].ravel())
         offs = np.cumsum([0] + [n // 8 for n in sizes])
         nblk = len(chunks)
         n8 = planes.shape[1]

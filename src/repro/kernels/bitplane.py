@@ -5,10 +5,10 @@ controller; on a TPU system the bit-plane layout lives in the offload
 tier / HBM and the transpose+reconstruction run on-chip, next to the
 consumer.  These kernels stream (R, C) uint16 tiles through VMEM:
 
-* ``pack_kernel``    — (R, C) u16 → (16, R, C//8) u8 plane stack.  One
-  grid step owns a (Br, C) row stripe; all 16 output planes of that
-  stripe are produced in-register (the bit-matrix transpose never touches
-  HBM, mirroring the paper's "transposition fully overlapped" claim).
+* ``pack_kernel``    — (R, C) u16 → (16, R, C//8) u8 plane stack.  The
+  wrapper lays the input out as (8, R, C//8) (byte-member-major); one
+  grid step owns a (Br, C) row stripe and produces all 16 output planes
+  of it in-register.
 * ``unpack_kernel``  — inverse, with *elastic* plane masking + guard-plane
   round-to-nearest-even fused in (Eq. 6/7): unfetched planes are never
   read (their BlockSpec rows are masked out by zeroing — on real TPU the
@@ -18,7 +18,7 @@ consumer.  These kernels stream (R, C) uint16 tiles through VMEM:
 Block shapes: C is kept whole per grid step (plane bytes stay contiguous
 along the minor axis — lane-dim friendly, multiples of 128 bytes when
 C ≥ 1024); Br rows per step bound VMEM: Br·C·2 B in + 16·Br·C/8 B out =
-4·Br·C bytes ≈ 1 MiB at the default (64, 4096).
+4·Br·C bytes = 256 KiB at the encode slab's (64, 1024).
 """
 
 from __future__ import annotations
@@ -31,21 +31,27 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..core.bitplane import BF16_BITS
+from . import dispatch
 
 DEFAULT_BLOCK_R = 64
+_PACK_C = 1024             # slab row width: C // 8 = 128 plane bytes
+_PACK_MIN_ELEMS = 8 * _PACK_C
 
 
 def _pack_kernel(x_ref, out_ref):
-    """x: (Br, C) u16 → out: (16, Br, C//8) u8."""
+    """x: (8, Br, C//8) u16 with ``x[j, r, k]`` = element ``8k + j`` of row
+    ``r`` → out: (16, Br, C//8) u8, MSB-first within each byte.
+
+    The wrapper moves the 8 elements of an output byte onto the leading
+    axis, so the kernel is pure elementwise work on (Br, C//8) tiles:
+    splitting the lane dim in-kernel is a reshape the TPU compiler
+    refuses."""
     x = x_ref[...].astype(jnp.int32)
-    br, c = x.shape
-    # bit i of every element, for all 16 planes: (16, Br, C)
     shifts = jax.lax.broadcasted_iota(jnp.int32, (BF16_BITS, 1, 1), 0)
-    bits = (x[None] >> shifts) & 1
-    # pack groups of 8 along C, MSB-first: weights 128..1
-    grouped = bits.reshape(BF16_BITS, br, c // 8, 8)
-    w = (128 >> jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 8), 3))
-    out_ref[...] = jnp.sum(grouped * w, axis=-1).astype(jnp.uint8)
+    acc = ((x[0][None] >> shifts) & 1) << 7
+    for j in range(1, 8):
+        acc |= ((x[j][None] >> shifts) & 1) << (7 - j)
+    out_ref[...] = acc.astype(jnp.uint8)
 
 
 def _unpack_kernel(planes_ref, out_ref, *, keep_mask: int, cut: int,
@@ -83,26 +89,20 @@ def _unpack_kernel(planes_ref, out_ref, *, keep_mask: int, cut: int,
     out_ref[...] = (u & keep_mask).astype(jnp.uint16)
 
 
-def _accel_backend() -> str:
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover - no runtime available
-        return "cpu"
-
-
 def pack_planes_slab(flat_u16, force: str | None = None):
     """Pack a flat ``(n,)`` uint16 encode slab to ``(16, n // 8)`` uint8
     planes — the write-side pack primitive of the batched encode pipeline.
 
-    Dispatch: on an accelerator backend (TPU/GPU) the slab is reshaped to
-    a 2-D tile and packed by :func:`pack_planes_pallas` (compiled; the
-    bit-matrix transpose never leaves VMEM); anywhere else the numpy
-    :func:`~repro.core.bitplane.pack_planes` path runs.  Both produce the
-    same bytes — plane streams are element-order packed, so a row-major
-    ``(R, C)`` reshape concatenates back to the flat stream exactly.
+    Dispatch (:func:`repro.kernels.dispatch.choose`): on a TPU the slab is
+    zero-padded to a power-of-two number of ``_PACK_C``-element rows and
+    packed by :func:`pack_planes_pallas`, so every slab takes the kernel
+    and the compiled shapes stay few; elsewhere the numpy
+    :func:`~repro.core.bitplane.pack_planes` twin runs.  Both produce the
+    same bytes — plane streams are element-order packed, so the padding
+    only appends bytes past ``n // 8`` that are sliced off.
 
-    ``force``: ``"numpy"`` pins the fallback; ``"pallas"`` pins the kernel
-    (interpret mode off-accelerator — used by the equivalence tests).
+    ``force``: ``"numpy"`` pins the twin; ``"pallas"`` pins the kernel
+    (interpret mode off the TPU — used by the equivalence tests).
     """
     from ..core.bitplane import pack_planes
 
@@ -110,42 +110,41 @@ def pack_planes_slab(flat_u16, force: str | None = None):
     n = flat.size
     if n % 8:
         raise ValueError(f"slab length {n} not a multiple of 8")
-    backend = _accel_backend()
-    use_pallas = (force == "pallas"
-                  or (force is None and backend in ("tpu", "gpu")))
-    if not use_pallas or n == 0:
+    if n == 0:
         return pack_planes(flat)
-    # factor n into (R, C) with C % 8 == 0; fall back if n is too ragged
-    for C in (4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8):
-        if n % C == 0:
-            break
-    else:
+    path = dispatch.choose("pack", force)
+    if path == dispatch.NUMPY:
         return pack_planes(flat)
-    R = n // C
-    for br in (DEFAULT_BLOCK_R, 32, 16, 8, 4, 2, 1):
-        if R % br == 0:
-            break
+    npad = max(_PACK_MIN_ELEMS, dispatch.bucket(n))
+    rows = npad // _PACK_C
+    x = np.zeros(npad, dtype=np.uint16)
+    x[:n] = flat
     planes = pack_planes_pallas(
-        jnp.asarray(flat.reshape(R, C)), block_r=br,
-        interpret=backend not in ("tpu", "gpu"),
+        jnp.asarray(x.reshape(rows, _PACK_C)),
+        block_r=min(rows, DEFAULT_BLOCK_R),
+        interpret=path == dispatch.INTERPRET,
     )
-    return np.asarray(planes).reshape(BF16_BITS, n // 8)
+    planes = np.asarray(planes).reshape(BF16_BITS, npad // 8)
+    return np.ascontiguousarray(planes[:, : n // 8])
 
 
+@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
 def pack_planes_pallas(x_u16: jnp.ndarray, block_r: int = DEFAULT_BLOCK_R,
                        interpret: bool = True) -> jnp.ndarray:
     """(R, C) uint16 → (16, R, C//8) uint8 (C % 8 == 0, R % block_r == 0)."""
     R, C = x_u16.shape
     br = min(block_r, R)
     assert R % br == 0 and C % 8 == 0
+    c8 = C // 8
+    xt = x_u16.reshape(R, c8, 8).transpose(2, 0, 1)
     return pl.pallas_call(
         _pack_kernel,
         grid=(R // br,),
-        in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((BF16_BITS, br, C // 8), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BF16_BITS, R, C // 8), jnp.uint8),
+        in_specs=[pl.BlockSpec((8, br, c8), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((BF16_BITS, br, c8), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((BF16_BITS, R, c8), jnp.uint8),
         interpret=interpret,
-    )(x_u16)
+    )(xt)
 
 
 def unpack_planes_pallas(planes: jnp.ndarray, *, r_e: int = 8, r_m: int = 7,

@@ -7,10 +7,9 @@ so the scalar python match loop the codec shipped with is no longer on
 the write path.  Four passes:
 
 1. **prep** — 4-byte little-endian words, multiplicative hashes and
-   byte-run boundaries for every position.  On accelerator backends this
-   is a pallas kernel (`_prep_kernel`, elementwise over shifted views of
-   the slab — the packed planes never leave the device for it);
-   elsewhere one vectorized numpy pass.
+   byte-run boundaries for every position.  On a TPU this is a pallas
+   kernel (`_prep_kernel`, elementwise over shifted views of
+   the slab); elsewhere one vectorized numpy pass.
 2. **previous occurrence** — the per-stream last-occurrence hash table,
    for all positions at once: one stable sort of stream-namespaced hash
    keys, then same-key adjacency.  Candidates can never cross a stream
@@ -28,20 +27,27 @@ The result is a compact ``(pos, dist, mlen)`` event tensor — selected
 matches in stream order.  Only the final byte-level token serialization
 (``codec.lz4_emit_events``) stays host-side.
 
-Dispatch mirrors ``bitplane.pack_planes_slab``: the device path (pallas
-prep + jnp passes under one jit) runs on TPU/GPU backends or under
-``force="device"`` (interpret-mode pallas off-accelerator — the
-equivalence tests); the numpy path runs anywhere and is the CPU
-production encoder.  Both are byte-identical to the scalar reference —
-``codec.lz4_compress_batch`` differential-tests them against
-``TRACE_SCALAR_LZ4=1``.
+Dispatch goes through ``kernels.dispatch.choose``, like
+``bitplane.pack_planes_slab``: the device path (pallas prep + jnp passes
+under one jit) runs on a TPU or under ``force="device"`` (interpret-mode
+pallas off the TPU — the equivalence tests); the numpy path runs
+anywhere else and is the CPU production encoder.  Both are
+byte-identical to the scalar reference — ``codec.lz4_compress_batch``
+differential-tests them against ``TRACE_SCALAR_LZ4=1``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+
+from . import dispatch
 
 # LZ4 block-format constants + repo match-policy knobs.  core.codec's
 # scalar reference mirrors these (asserted at dispatch time there): a
@@ -57,42 +63,30 @@ RUN_STRIDE = 4        # interior byte-run positions keep a candidate only
 _EMPTY = (np.empty(0, np.int64),) * 3
 
 
-def _accel_backend() -> str:
-    try:
-        import jax
-
-        return jax.default_backend()
-    except (ImportError, RuntimeError):  # pragma: no cover - no runtime
-        return "cpu"
-
-
 def match_events_slab(slab, starts, ends,
                       force: str | None = None
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Greedy LZ4 match events for every stream of a concatenated slab.
 
-    ``slab`` is a flat uint8 buffer (numpy, or a device array on the
-    accelerator path — e.g. the ravelled output of ``pack_planes_slab``);
-    ``starts``/``ends`` bound each stream's half-open byte range, disjoint
-    and ascending (gaps — bypassed streams — are allowed and never
-    touched).  Returns ``(pos, dist, mlen)`` int64 arrays sorted by
+    ``slab`` is a flat uint8 buffer (e.g. the ravelled output of
+    ``pack_planes_slab``); ``starts``/``ends`` bound each stream's
+    half-open byte range, disjoint and ascending (gaps — bypassed
+    streams — are allowed and never touched).  Returns ``(pos, dist, mlen)`` int64 arrays sorted by
     global position: the matches a per-stream scalar
     ``codec._lz4_events_scalar`` scan would select, bit for bit.
 
     ``force``: ``"numpy"`` pins the vectorized-numpy fallback,
-    ``"device"`` pins the pallas+jnp path (interpret mode off
-    accelerator); default dispatches on the jax backend.
+    ``"device"`` pins the pallas+jnp path (interpret mode off the
+    TPU); default dispatches through ``kernels.dispatch.choose``.
     """
     starts = np.asarray(starts, dtype=np.int64).ravel()
     ends = np.asarray(ends, dtype=np.int64).ravel()
     if starts.size == 0:
         return _EMPTY
-    backend = _accel_backend()
-    use_device = (force == "device"
-                  or (force is None and backend in ("tpu", "gpu")))
-    if use_device:
+    path = dispatch.choose("lz4", force)
+    if path != dispatch.NUMPY:
         return _match_events_device(
-            slab, starts, ends, interpret=backend not in ("tpu", "gpu"))
+            slab, starts, ends, interpret=path == dispatch.INTERPRET)
     buf = np.asarray(slab, dtype=np.uint8).ravel()
     return _match_events_numpy(buf, starts, ends)
 
@@ -117,23 +111,6 @@ def _words_hashes(buf: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     h = w * np.uint32(2654435761)
     np.right_shift(h, np.uint32(32 - HASH_LOG), out=h)
     return w, h.astype(np.uint16)
-
-
-def _stream_ids(n_pos: int, starts: np.ndarray,
-                ends: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Dense (sid, covered) maps for positions 0..n_pos-1 — O(N), no
-    per-position searchsorted.  ``sid`` is meaningful only where
-    ``covered``."""
-    s = starts[starts < n_pos]
-    e = np.minimum(ends, n_pos)
-    marks = np.zeros(n_pos + 1, dtype=np.int64)
-    np.add.at(marks, s, 1)
-    sid = np.cumsum(marks[:-1]) - 1
-    cover = np.zeros(n_pos + 1, dtype=np.int64)
-    np.add.at(cover, s, 1)
-    np.subtract.at(cover, e, 1)
-    covered = np.cumsum(cover[:-1]) > 0
-    return sid, covered
 
 
 _SWEEP_CAP = 32        # capped-LCP sweep bound for offsets > 1; NOT an
@@ -483,30 +460,33 @@ _PREP_BLOCK = 256     # rows per grid step; 128-byte minor axis (lane dim)
 _PREP_C = 128
 
 
+_HASH_MUL = np.int32(2654435761 - (1 << 32))   # the LZ4 multiplier, as int32
+
+
 def _prep_kernel(b0_ref, b1_ref, b2_ref, b3_ref, w_ref, h_ref, run_ref):
     """Elementwise prep over shifted slab views: 4-byte LE word, hash,
     and run-boundary flag per position.  Pure array ops — the R6 lint
-    holds this body host-sync-free."""
-    import jax.numpy as jnp
+    holds this body host-sync-free.
 
-    b0 = b0_ref[...].astype(jnp.uint32)
-    b1 = b1_ref[...].astype(jnp.uint32)
-    b2 = b2_ref[...].astype(jnp.uint32)
-    b3 = b3_ref[...].astype(jnp.uint32)
+    All int32: the TPU compiler has no unsigned compare.  The word keeps
+    its uint32 bit pattern (only equality is asked of it downstream), the
+    wrapping multiply has the same low 32 bits, and a logical shift
+    extracts the same top ``HASH_LOG`` bits as the uint32 hash."""
+    b0 = b0_ref[...].astype(jnp.int32)
+    b1 = b1_ref[...].astype(jnp.int32)
+    b2 = b2_ref[...].astype(jnp.int32)
+    b3 = b3_ref[...].astype(jnp.int32)
     w = b0 | (b1 << 8) | (b2 << 16) | (b3 << 24)
     w_ref[...] = w
-    h_ref[...] = ((w * jnp.uint32(2654435761))
-                  >> jnp.uint32(32 - HASH_LOG)).astype(jnp.int32)
-    run_ref[...] = (b0 != b1).astype(jnp.int32)
+    h_ref[...] = lax.shift_right_logical(w * _HASH_MUL, 32 - HASH_LOG)
+    # compare a difference: a compare straight on the widened bytes
+    # lowers to an 8-bit compare, which the TPU compiler also refuses
+    run_ref[...] = jnp.where(b0 - b1 != 0, 1, 0)
 
 
 def _prep_pallas(buf, interpret: bool):
-    """(N,) uint8 device slab → (w, h, runb) arrays of length N (tail
-    entries are garbage the downstream masks never read)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
+    """(N,) uint8 device slab → (w, h, runb) int32 arrays of length N
+    (tail entries are garbage the downstream masks never read)."""
     n = buf.shape[0]
     tile = _PREP_BLOCK * _PREP_C
     pad = (-n - 3) % tile + 3          # room for the +3 shifted views
@@ -522,11 +502,7 @@ def _prep_pallas(buf, interpret: bool):
         grid=grid,
         in_specs=[spec] * 4,
         out_specs=[spec] * 3,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, _PREP_C), jnp.uint32),
-            jax.ShapeDtypeStruct((rows, _PREP_C), jnp.int32),
-            jax.ShapeDtypeStruct((rows, _PREP_C), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((rows, _PREP_C), jnp.int32)] * 3,
         interpret=interpret,
     )(*shifted)
     return (w.reshape(-1)[:n], h.reshape(-1)[:n], runb.reshape(-1)[:n])
@@ -534,108 +510,101 @@ def _prep_pallas(buf, interpret: bool):
 
 def _match_events_device(slab, starts, ends, interpret: bool):
     """Pallas prep + jnp match pipeline in one device launch; only the
-    compacted event tensor returns to the host."""
-    import jax.numpy as jnp
+    compacted event tensor returns to the host.
 
-    buf_np = None
-    if isinstance(slab, np.ndarray):
-        buf_np = slab.astype(np.uint8, copy=False).ravel()
-        N = int(buf_np.size)
-    else:
-        N = int(np.prod(slab.shape))
+    On the device every stream gets a row of its own, ``L`` bytes wide
+    (the longest stream, rounded up to a power of two).  Candidates never
+    cross a stream, so the hash table becomes a sort along each row, and
+    the next-candidate and run-end tables become suffix minima along each
+    row: all compile in seconds, where one sort over the whole slab takes
+    the TPU compiler minutes.  The slab length, the stream count and the
+    event bound are rounded up to powers of two as well, so a serve run
+    compiles a handful of shapes rather than one per flush; padding
+    streams are empty rows that no cursor enters."""
+    N = int(np.prod(slab.shape))
     if N < MIN_MATCH:
         return _EMPTY
-    # static geometry → dense masks (host-computed constants, passed as
-    # device operands so the jitted pipeline stays pure array code)
-    npos = N - 3
-    sid, covered = _stream_ids(npos, starts, ends)
-    valid = covered & (np.arange(npos) + MIN_MATCH <= ends[sid])
-    local = np.arange(npos) - starts[np.minimum(sid, starts.size - 1)]
-    nb = (ends - starts)[np.minimum(sid, starts.size - 1)]
-    start_ok = valid & (local < nb - MFLIMIT)
-    stride_ok = (local >= 2) & (local % RUN_STRIDE != 0)
-    # per-stream event bound: matches never overlap and are ≥ MIN_MATCH
-    sizes = ends - starts
-    row_start = np.concatenate(
-        ([0], np.cumsum(sizes // MIN_MATCH + 1)))
-    E = int(row_start[-1])
     S = starts.size
-
-    dev = jnp.asarray(slab, dtype=jnp.uint8).reshape(-1)
+    sizes = ends - starts
+    L = dispatch.bucket(max(int(sizes.max()), 8))
+    st = np.zeros(dispatch.bucket(S), dtype=np.int32)
+    sz = np.zeros(dispatch.bucket(S), dtype=np.int32)
+    st[:S], sz[:S] = starts, sizes
+    # per-stream event bound: matches never overlap and are ≥ MIN_MATCH
+    row_start = np.concatenate(([0], np.cumsum(sz // MIN_MATCH + 1)))
+    E = dispatch.bucket(int(row_start[-1]))
+    padded = np.zeros(dispatch.bucket(N), dtype=np.uint8)
+    padded[:N] = np.asarray(slab, dtype=np.uint8).ravel()
     pos, dist, mlen, count = _device_match(
-        dev, jnp.asarray(sid), jnp.asarray(valid), jnp.asarray(start_ok),
-        jnp.asarray(stride_ok), jnp.asarray(starts), jnp.asarray(ends),
-        jnp.asarray(row_start[:-1]), E, interpret)
-    pos = np.asarray(pos)
-    dist = np.asarray(dist)
-    mlen = np.asarray(mlen)
+        jnp.asarray(padded), jnp.asarray(st), jnp.asarray(sz),
+        jnp.asarray(row_start[:-1], dtype=np.int32),
+        L=L, E=E, interpret=interpret)
     count = np.asarray(count)
     keep = np.concatenate([
         np.arange(row_start[s], row_start[s] + count[s]) for s in range(S)
-    ]) if S else np.empty(0, np.int64)
-    pos, dist, mlen = (pos[keep].astype(np.int64),
-                       dist[keep].astype(np.int64),
-                       mlen[keep].astype(np.int64))
-    order = np.argsort(pos, kind="stable")
-    return pos[order], dist[order], mlen[order]
+    ])
+    pos = np.asarray(pos)[keep].astype(np.int64)
+    row = pos // L
+    pos = pos - row * L + starts[row]          # row layout → slab offsets
+    return (pos, np.asarray(dist)[keep].astype(np.int64),
+            np.asarray(mlen)[keep].astype(np.int64))
 
 
-def _device_match(buf, sid, valid, start_ok, stride_ok, starts, ends,
-                  row_start, E: int, interpret: bool):
-    import jax
+def _suffix_min(x, fill: int):
+    """Minimum over ``x[:, j:]`` for every ``j``: log2(L) shifted mins."""
+    k = 1
+    while k < x.shape[1]:
+        pad = jnp.full((x.shape[0], k), fill, x.dtype)
+        x = jnp.minimum(x, jnp.concatenate([x[:, k:], pad], axis=1))
+        k *= 2
+    return x
 
-    fn = jax.jit(_device_match_impl,
-                 static_argnames=("E", "interpret"))
-    return fn(buf, sid, valid, start_ok, stride_ok, starts, ends,
-              row_start, E=E, interpret=interpret)
 
-
-def _device_match_impl(buf, sid, valid, start_ok, stride_ok, starts, ends,
-                       row_start, *, E: int, interpret: bool):
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    N = buf.shape[0]
-    npos = N - 3
+@functools.partial(jax.jit, static_argnames=("L", "E", "interpret"))
+def _device_match(slab, starts, sizes, row_start, *, L: int, E: int,
+                  interpret: bool):
     S = starts.shape[0]
+    o = lax.broadcasted_iota(jnp.int32, (S, L), 1)          # row offset
+    src = jnp.clip(starts[:, None] + o, 0, slab.shape[0] - 1)
+    inside = o < sizes[:, None]
+    buf = jnp.where(inside, slab[src], 0).astype(jnp.uint8).reshape(-1)
+    N = S * L
     w, h, runb = _prep_pallas(buf, interpret)
-    w, h = w[:npos], h[:npos]
-    iota = jnp.arange(npos, dtype=jnp.int32)
-    BIG = jnp.int32(S) * HASH_SIZE + HASH_SIZE
-    keys = jnp.where(valid, sid.astype(jnp.int32) * HASH_SIZE
-                     + h.astype(jnp.int32), BIG)
-    # stable sort of (key, position): previous same-key occurrence is the
-    # sorted neighbour — the whole hash table in one pass
-    order = jnp.argsort(keys, stable=True)
-    sk = keys[order]
-    same = (sk[1:] == sk[:-1]) & (sk[1:] < BIG)
-    prev = jnp.full(npos, -1, dtype=jnp.int32)
-    prev = prev.at[order[1:]].set(jnp.where(same, order[:-1], -1))
+    iota = jnp.arange(N, dtype=jnp.int32)
+    sid = iota // L
+    valid = o + MIN_MATCH <= sizes[:, None]
+    start_ok = (valid & (o < sizes[:, None] - MFLIMIT)).reshape(-1)
+    stride_ok = ((o >= 2) & (o % RUN_STRIDE != 0)).reshape(-1)
+    # stable sort of each row's hashes: the previous same-hash occurrence
+    # is the sorted neighbour — the stream's whole hash table in one pass
+    keys = jnp.where(valid, h.reshape(S, L), HASH_SIZE)
+    sk, so = lax.sort((keys, o), dimension=1, is_stable=True, num_keys=1)
+    same = (sk[:, 1:] == sk[:, :-1]) & (sk[:, 1:] < HASH_SIZE)
+    rows = lax.broadcasted_iota(jnp.int32, (S, L - 1), 0)
+    prev = jnp.full((S, L), -1, dtype=jnp.int32).at[rows, so[:, 1:]].set(
+        jnp.where(same, rows * L + so[:, :-1], -1)).reshape(-1)
     dist = iota - prev
-    bufm2 = jnp.concatenate([jnp.zeros(2, buf.dtype), buf[:-2]])[:npos]
-    bufm1 = jnp.concatenate([jnp.zeros(1, buf.dtype), buf[:-1]])[:npos]
+    bufm2 = jnp.concatenate([jnp.zeros(2, buf.dtype), buf[:-2]])
+    bufm1 = jnp.concatenate([jnp.zeros(1, buf.dtype), buf[:-1]])
     ok = (start_ok & (prev >= 0) & (dist <= 0xFFFF)
-          & (w == w[jnp.clip(prev, 0, npos - 1)]))
+          & (w == w[jnp.clip(prev, 0, N - 1)]))
     ok &= ~((dist == 1) & stride_ok & (bufm2 == bufm1))
-    # next-candidate-at-or-after + run-end tables: reverse cumulative mins
-    ncand = lax.cummin(jnp.where(ok, iota, npos), reverse=True)
-    run_last = lax.cummin(
-        jnp.where(runb[: N - 1] > 0, jnp.arange(N - 1, dtype=jnp.int32),
-                  N - 1),
-        reverse=True)
-    run_last = jnp.concatenate([run_last, jnp.full(1, N - 1, jnp.int32)])
+    # next candidate at or after each position, and the last byte of the
+    # byte run through it (every run length is capped inside its stream)
+    ncand = _suffix_min(jnp.where(ok, iota, N).reshape(S, L), N).reshape(-1)
+    run_last = _suffix_min(
+        jnp.where(runb > 0, iota, iota | (L - 1)).reshape(S, L),
+        N).reshape(-1)
 
     sids = jnp.arange(S, dtype=jnp.int32)
-    max_end = ends - LAST_LITERALS
+    max_end = sids * L + sizes - LAST_LITERALS
 
     def cursor_of(p):
-        c = ncand[jnp.clip(p, 0, npos - 1)]
-        live = (p < npos) & (c < npos) & (sid[jnp.clip(c, 0, npos - 1)]
-                                          == sids)
-        return jnp.where(live, c, npos), live
+        c = ncand[jnp.clip(p, 0, N - 1)]
+        live = (p < N) & (c < N) & (sid[jnp.clip(c, 0, N - 1)] == sids)
+        return jnp.where(live, c, N), live
 
-    cur0, live0 = cursor_of(starts)
+    cur0, live0 = cursor_of(sids * L)
 
     def lcp_round(p, d, live):
         cap = max_end - p
@@ -650,8 +619,8 @@ def _device_match_impl(buf, sid, valid, start_ok, stride_ok, starts, ends,
 
         def gallop_body(st):
             m_, _ = st
-            gi = jnp.clip(p + m_, 0, npos - 1)
-            ci = jnp.clip(c + m_, 0, npos - 1)
+            gi = jnp.clip(p + m_, 0, N - 1)
+            ci = jnp.clip(c + m_, 0, N - 1)
             adv = live & ~run & (m_ + 4 <= cap) & (w[gi] == w[ci])
             return m_ + 4 * adv, adv
 
@@ -665,12 +634,11 @@ def _device_match_impl(buf, sid, valid, start_ok, stride_ok, starts, ends,
         return jnp.where(run, m_run, jnp.where(live, m, MIN_MATCH))
 
     def cond(state):
-        _, _, _, _, live, _ = state
-        return jnp.any(live)
+        return jnp.any(state[3])
 
     def body(state):
-        cur, count, out, nxt_unused, live, _ = state
-        ci = jnp.clip(cur, 0, npos - 1)
+        cur, count, out, live = state
+        ci = jnp.clip(cur, 0, N - 1)
         p = iota[ci]
         d = dist[ci]
         m = lcp_round(p, d, live)
@@ -679,12 +647,11 @@ def _device_match_impl(buf, sid, valid, start_ok, stride_ok, starts, ends,
                out[1].at[slot].set(jnp.where(live, d, 0), mode="drop"),
                out[2].at[slot].set(jnp.where(live, m, 0), mode="drop"))
         count = count + live
-        ncur, nlive = cursor_of(jnp.where(live, p + m, npos))
-        nlive &= live
-        # a cursor that jumped into another stream's range is dead
-        return (ncur, count, out, nxt_unused, nlive, 0)
+        # a cursor that jumped into another stream's row is dead
+        ncur, nlive = cursor_of(jnp.where(live, p + m, N))
+        return (ncur, count, out, nlive & live)
 
     out0 = tuple(jnp.zeros(E + 1, jnp.int32) for _ in range(3))
-    cur, count, out, _, _, _ = lax.while_loop(
-        cond, body, (cur0, jnp.zeros(S, jnp.int32), out0, 0, live0, 0))
+    _, count, out, _ = lax.while_loop(
+        cond, body, (cur0, jnp.zeros(S, jnp.int32), out0, live0))
     return out[0][:E], out[1][:E], out[2][:E], count

@@ -16,11 +16,14 @@ the dynamic-placement literature's top-k page selection signal).
 
 Twin implementations, mirroring ``kernels/lz4.py`` / ``bitplane.py``:
 
-* ``page_scores_pallas`` — a pallas kernel (one grid step per page; the
-  masked dot+max reduction stays in VMEM), compiled on TPU/GPU and run
-  in interpret mode for the CPU parity tests;
+* ``page_scores_pallas`` — a pallas kernel (one grid step per block of
+  8 pages; the masked dot+max reduction stays in VMEM), compiled on a
+  TPU and run in interpret mode for the CPU parity tests;
 * the vectorized-numpy twin inside :func:`page_scores` — the CPU
   production path the tier device calls.
+
+The two are byte-identical (see :func:`page_scores`), so a gather picks
+the same winners on either backend.
 
 Determinism: winner selection must be bit-stable across sync/async
 submission and shard counts, so :func:`topk_select` ranks by
@@ -32,26 +35,22 @@ byte-identical duplicate pages.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-
-def _accel_backend() -> str:
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover - no runtime available
-        return "cpu"
+from . import dispatch
 
 
 def u16_rows_to_f32(u16: np.ndarray, channels: int) -> np.ndarray:
     """Reinterpret a device payload (uint16 bf16 bit patterns) as
     ``(tokens, channels)`` float32 rows for scoring."""
-    import ml_dtypes
-
     flat = np.ascontiguousarray(np.asarray(u16, dtype=np.uint16)).ravel()
     if flat.size % channels:
         raise ValueError(
@@ -63,35 +62,63 @@ def u16_rows_to_f32(u16: np.ndarray, channels: int) -> np.ndarray:
             .reshape(-1, channels))
 
 
-def _score_kernel(valid_ref, digest_ref, page_ref, out_ref):
-    """One grid step scores one page: masked row-dot + max in VMEM."""
-    page = page_ref[0]                    # (T, C) f32
-    digest = digest_ref[0]                # (C,) f32
-    v = valid_ref[0, 0]                   # valid token rows
-    dots = jnp.sum(page * digest[None, :], axis=-1)       # (T,)
-    t_ix = jax.lax.broadcasted_iota(jnp.int32, dots.shape, 0)
-    out_ref[0] = jnp.max(jnp.where(t_ix < v, dots, -jnp.inf))
+_PAGE_BLOCK = 8       # pages per grid step: one sublane tile
+_LANES = 128
 
 
+def _tree_sum(terms: list):
+    """Sum a power-of-two list by halving: ``t[i] + t[i + n/2]`` per level.
+    Both twins add in exactly this order, so their f32 sums agree bit for
+    bit."""
+    while len(terms) > 1:
+        h = len(terms) // 2
+        terms = [terms[i] + terms[i + h] for i in range(h)]
+    return terms[0]
+
+
+def _score_kernel(digest_ref, valid_ref, page_ref, out_ref):
+    """One grid step scores ``_PAGE_BLOCK`` pages: per-channel products,
+    halving-tree sum over channels, masked max over token rows.
+
+    digest (Cp,) f32 in SMEM; valid (PB, 1) i32; pages (Cp, PB, T) f32,
+    channel-major so every term is a whole (PB, T) tile; out (PB, 128)
+    with the score broadcast along lanes."""
+    dots = _tree_sum([page_ref[c] * digest_ref[c]
+                      for c in range(page_ref.shape[0])])      # (PB, T)
+    t_ix = jax.lax.broadcasted_iota(jnp.int32, dots.shape, 1)
+    best = jnp.max(jnp.where(t_ix < valid_ref[...], dots, -jnp.inf),
+                   axis=1, keepdims=True)
+    out_ref[...] = jnp.broadcast_to(best, out_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def page_scores_pallas(padded: jnp.ndarray, valid: jnp.ndarray,
                        digest: jnp.ndarray,
                        interpret: bool = True) -> jnp.ndarray:
-    """(P, T, C) f32 pages + (P,) valid lens + (C,) digest → (P,) f32."""
+    """(P, T, C) f32 pages + (P,) valid lens + (C,) digest → (P,) f32.
+
+    Channels are zero-padded to a power of two and pages to a multiple of
+    ``_PAGE_BLOCK``; the numpy twin pads channels the same way."""
     P, T, C = padded.shape
-    return pl.pallas_call(
+    cp = dispatch.bucket(C)
+    pp = -(-P // _PAGE_BLOCK) * _PAGE_BLOCK
+    pages = jnp.pad(padded.astype(jnp.float32),
+                    ((0, pp - P), (0, 0), (0, cp - C))).transpose(2, 0, 1)
+    out = pl.pallas_call(
         _score_kernel,
-        grid=(P,),
+        grid=(pp // _PAGE_BLOCK,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, C), lambda i: (0, 0)),
-            pl.BlockSpec((1, T, C), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((_PAGE_BLOCK, 1), lambda i: (i, 0)),
+            pl.BlockSpec((cp, _PAGE_BLOCK, T), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((P,), jnp.float32),
+        out_specs=pl.BlockSpec((_PAGE_BLOCK, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((pp, _LANES), jnp.float32),
         interpret=interpret,
-    )(valid.reshape(P, 1).astype(jnp.int32),
-      digest.reshape(1, C).astype(jnp.float32),
-      padded.astype(jnp.float32))
+    )(jnp.pad(digest.astype(jnp.float32), (0, cp - C)),
+      jnp.pad(valid.astype(jnp.int32), (0, pp - P)).reshape(pp, 1),
+      pages)
+    return out[:P, 0]
 
 
 def page_scores(padded: np.ndarray, valid: np.ndarray, digest: np.ndarray,
@@ -99,26 +126,37 @@ def page_scores(padded: np.ndarray, valid: np.ndarray, digest: np.ndarray,
     """Score a padded page stack: ``(P, T, C)`` f32 rows (rows past
     ``valid[p]`` ignored) against a ``(C,)`` digest → ``(P,)`` f32.
 
-    Pages with zero valid rows score ``-inf`` (they rank last, ties by
-    candidate position).  ``force``: ``"numpy"`` pins the vectorized
-    twin, ``"pallas"`` pins the kernel (interpret mode off-accelerator).
+    The digest is rounded to bf16 first.  Page rows are bf16 values, so
+    every row·digest product is then exact in f32, and with the shared
+    halving-tree channel sum the device kernel and the numpy twin return
+    identical bytes.  Pages with zero valid rows score ``-inf`` (they rank
+    last, ties by candidate position).  ``force``: ``"numpy"`` pins the
+    twin, ``"pallas"`` pins the kernel (interpret mode off the TPU).
     """
     padded = np.asarray(padded, dtype=np.float32)
     valid = np.asarray(valid, dtype=np.int64)
-    digest = np.asarray(digest, dtype=np.float32)
+    digest = (np.asarray(digest, dtype=np.float32)
+              .astype(ml_dtypes.bfloat16).astype(np.float32))
     P, T, C = padded.shape
     if P == 0 or T == 0:
         return np.full((P,), -np.inf, dtype=np.float32)
-    backend = _accel_backend()
-    use_pallas = (force == "pallas"
-                  or (force is None and backend in ("tpu", "gpu")))
-    if use_pallas:
+    path = dispatch.choose("score", force)
+    if path != dispatch.NUMPY:
+        # bucket the page count so a serve run compiles few shapes
+        pp = max(_PAGE_BLOCK, dispatch.bucket(P))
+        pages = np.zeros((pp, T, C), dtype=np.float32)
+        pages[:P] = padded
+        lens = np.zeros(pp, dtype=np.int32)
+        lens[:P] = valid
         out = page_scores_pallas(
-            jnp.asarray(padded), jnp.asarray(valid), jnp.asarray(digest),
-            interpret=backend not in ("tpu", "gpu"),
+            jnp.asarray(pages), jnp.asarray(lens), jnp.asarray(digest),
+            interpret=path == dispatch.INTERPRET,
         )
-        return np.asarray(out, dtype=np.float32)
-    dots = padded @ digest                                # (P, T)
+        return np.asarray(out, dtype=np.float32)[:P]
+    cp = dispatch.bucket(C)
+    prod = np.zeros((P, T, cp), dtype=np.float32)
+    prod[..., :C] = padded * digest
+    dots = _tree_sum([prod[..., c] for c in range(cp)])     # (P, T)
     mask = np.arange(T)[None, :] < valid[:, None]
     return np.where(mask, dots, -np.inf).max(axis=1).astype(np.float32)
 

@@ -18,21 +18,14 @@ per (arch × shape) cell:
 from __future__ import annotations
 
 import jax
-
-try:  # jax ≥ 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax has Auto-only meshes
-    AxisType = None
+from jax.sharding import AxisType
 
 from ..configs.base import ArchConfig, ShapeConfig
 from ..models.sharding import MeshRules
 
 
 def _mk_mesh(shape: tuple, axes: tuple):
-    """jax.make_mesh with ``axis_types`` only where the installed jax has
-    it; older releases treat every axis as Auto already."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharding by annotation)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

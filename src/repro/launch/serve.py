@@ -9,11 +9,14 @@ share one device queue; ``--num-requests N`` switches to the
 continuous-batching scheduler (Poisson/bursty arrivals, capacity-aware
 admission, retirement frees tier pages).
 
-Usage (CPU demo):
+Usage (CPU demo, reduced smoke config):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --tokens 64 --device trace --streams 2
   PYTHONPATH=src python -m repro.launch.serve --num-requests 8 \
       --arrival-rate 0.5 --max-batch 2
+On a TPU, ``--full-width`` serves the architecture at its published
+widths.  Figures named ``modeled_*`` are priced by the analytic link
+model, not measured.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ import argparse
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import ARCHS, smoke_config
 from ..core import make_device, synth
 from ..core.precision import VIEWS
@@ -31,6 +35,13 @@ from ..runtime import (
     MultiStreamEngine, PAPER_POLICY, ServeEngine, ServeScheduler,
 )
 from ..runtime.paging import DEFAULT_DEGRADE_LADDER, LOSSLESS_POLICY
+
+
+def device_line() -> str:
+    """The device JAX serves on, as it reports it."""
+    devs = jax.devices()
+    return (f"[serve] device: platform={devs[0].platform} "
+            f"kind={devs[0].device_kind} count={len(devs)}")
 
 
 def parse_degrade_ladder(spec: str):
@@ -174,9 +185,10 @@ def serve(
               f"DRAM read {d.dram_bytes_read} B, link out {d.link_bytes_out} B")
         io_srv = sum(s.tier_io_service_s for s in per)
         io_qd = sum(s.tier_io_queue_delay_s for s in per)
-        print(f"[serve] tier I/O: serialized {io_srv * 1e3:.3f} ms, "
-              f"queue delay {io_qd * 1e3:.3f} ms")
-        print(f"[serve] aggregate tok/s ceiling: {eng.throughput_ceiling():.1f}")
+        print(f"[serve] tier I/O (modeled): modeled_serialized_ms "
+              f"{io_srv * 1e3:.3f}, modeled_queue_delay_ms {io_qd * 1e3:.3f}")
+        print(f"[serve] modeled_tok_s_ceiling (aggregate): "
+              f"{eng.throughput_ceiling():.1f}")
         return eng, toks
     eng = ServeEngine(cfg, params, device_kind=dev, **kw)
     if pnm_topk is not None:
@@ -191,9 +203,11 @@ def serve(
           f"tier stored {s.tier_dram_stored} B for {s.kv_logical_bytes} B logical "
           f"(ratio {s.kv_compression_ratio:.2f}x)")
     print(f"[serve] tier DRAM read {s.tier_dram_read} B, link out {s.tier_link_out} B")
-    print(f"[serve] tier I/O: serialized {s.tier_io_service_s * 1e3:.3f} ms, "
-          f"queue delay {s.tier_io_queue_delay_s * 1e3:.3f} ms")
-    print(f"[serve] tok/s ceiling (tier-bound): {eng.throughput_ceiling():.1f}")
+    print(f"[serve] tier I/O (modeled): modeled_serialized_ms "
+          f"{s.tier_io_service_s * 1e3:.3f}, modeled_queue_delay_ms "
+          f"{s.tier_io_queue_delay_s * 1e3:.3f}")
+    print(f"[serve] modeled_tok_s_ceiling (tier-bound): "
+          f"{eng.throughput_ceiling():.1f}")
     return eng, toks
 
 
@@ -254,21 +268,23 @@ def serve_continuous(
           f"{num_requests} requests, {arrival_kind} rate {arrival_rate}/round, "
           f"max_batch {max_batch}, capacity model {capacity_model}")
     print(f"[serve] {rep.steps} rounds, {rep.decode_tokens} decode tokens in "
-          f"{rep.model_time_s * 1e3:.2f} modeled ms → {rep.tok_s:.1f} tok/s "
-          f"(peak admitted batch {rep.peak_active})")
-    print(f"[serve] latency p50 {rep.p50_latency_s * 1e3:.2f} ms, "
-          f"p99 {rep.p99_latency_s * 1e3:.2f} ms, mean queue delay "
-          f"{rep.mean_queue_delay_s * 1e3:.2f} ms")
-    print(f"[serve] TTFT p50 {rep.p50_ttft_s * 1e3:.2f} ms, "
-          f"p99 {rep.p99_ttft_s * 1e3:.2f} ms; "
-          f"TPOT mean {rep.mean_tpot_s * 1e3:.2f} ms/tok")
+          f"modeled_ms {rep.model_time_s * 1e3:.2f} → modeled_tok_s "
+          f"{rep.tok_s:.1f} (peak admitted batch {rep.peak_active})")
+    print(f"[serve] modeled latency: modeled_p50_ms "
+          f"{rep.p50_latency_s * 1e3:.2f}, modeled_p99_ms "
+          f"{rep.p99_latency_s * 1e3:.2f}, modeled_mean_queue_delay_ms "
+          f"{rep.mean_queue_delay_s * 1e3:.2f}")
+    print(f"[serve] modeled_ttft_p50_ms {rep.p50_ttft_s * 1e3:.2f}, "
+          f"modeled_ttft_p99_ms {rep.p99_ttft_s * 1e3:.2f}; "
+          f"modeled_tpot_mean_ms {rep.mean_tpot_s * 1e3:.2f}")
     if slo_ttft_s is not None or slo_tpot_s is not None:
         targets = []
         if slo_ttft_s is not None:
             targets.append(f"TTFT <= {slo_ttft_s * 1e3:g} ms")
         if slo_tpot_s is not None:
             targets.append(f"TPOT <= {slo_tpot_s * 1e3:g} ms/tok")
-        print(f"[serve] SLO attainment {rep.slo_attainment * 100:.1f}% "
+        print(f"[serve] modeled SLO attainment "
+              f"{rep.slo_attainment * 100:.1f}% "
               f"({' and '.join(targets)}, "
               f"{len(rep.records)} finished requests)")
     if capacity_model == "physical":
@@ -296,6 +312,10 @@ def main():
         epilog=EPILOG, formatter_class=argparse.RawDescriptionHelpFormatter,
     )
     ap.add_argument("--arch", default="qwen2-0.5b", choices=sorted(ARCHS))
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the architecture at its published widths "
+                         "(default: the reduced smoke config, sized for "
+                         "the CPU)")
     ap.add_argument("--device", default="trace",
                     choices=["plain", "gcomp", "trace"])
     ap.add_argument("--tokens", type=int, default=32)
@@ -380,6 +400,8 @@ def main():
                          "busy-clock monotonicity and retire cleanup "
                          "(same as TRACE_SANITIZE=1)")
     args = ap.parse_args()
+    enable_compile_cache()
+    print(device_line())
     ladder = parse_degrade_ladder(args.degrade_ladder)
     if ladder and args.capacity_model != "physical":
         raise SystemExit(
@@ -395,7 +417,7 @@ def main():
             print("[serve] note: --streams is ignored in continuous-"
                   "batching mode (concurrency comes from --max-batch)")
         serve_continuous(
-            arch=args.arch, device=args.device,
+            arch=args.arch, smoke=not args.full_width, device=args.device,
             num_requests=args.num_requests, arrival_rate=args.arrival_rate,
             arrival_kind=args.arrival_kind, max_batch=args.max_batch,
             prompt_len=args.prompt_len, n_tokens=args.tokens,
@@ -421,7 +443,8 @@ def main():
         print("[serve] note: --prefix-share applies to continuous-"
               "batching mode (--num-requests N); single/multi-stream "
               "runs have no cross-request reuse")
-    serve(arch=args.arch, device=args.device, n_tokens=args.tokens,
+    serve(arch=args.arch, smoke=not args.full_width, device=args.device,
+          n_tokens=args.tokens,
           prompt_len=args.prompt_len, batch=args.batch,
           streams=args.streams, async_io=not args.sync_io,
           lossless_only=args.lossless_only,

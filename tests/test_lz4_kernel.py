@@ -148,7 +148,7 @@ def test_device_path_matches_numpy_path():
         np.testing.assert_array_equal(r, d)
     # ... and the full encode built on those events stays byte-identical
     chunks = [p.tobytes() for p in parts]
-    assert codec._lz4_slab_streams(buf, buf, starts, ends,
+    assert codec._lz4_slab_streams(buf, starts, ends,
                                    force="device") == _scalar_oracle(chunks)
 
 
