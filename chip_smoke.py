@@ -21,12 +21,11 @@ Phases, in order; any failure exits non-zero:
               spilled pages gives the same tokens again.
 4. schedule — ``ServeScheduler`` finishes every request and the tier
               holds 0 bytes after retirement.
-5. measure  — host-clock wall times that end in ``block_until_ready``,
-              the number of XLA compilations and the peak device memory.
 
-Throughout phases 3-5 every encode and gather in the tier must take the
-compiled device path (``repro.kernels.dispatch.CALLS``).  The last line
-of output is one JSON object:
+Throughout phases 3-4 every encode and gather in the tier must take the
+compiled device path (the ``kernel.<kernel>.<path>`` counters of
+``repro.obs``).  Times are the benchmark's (``benchmarks/serve``).  The
+last line of output is one JSON object:
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}``.
 """
 
@@ -34,9 +33,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import statistics
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -46,30 +43,23 @@ import jax.numpy as jnp  # noqa: E402
 import ml_dtypes  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import ARCHS, smoke_config  # noqa: E402
 from repro.core import codec  # noqa: E402
 from repro.core.bitplane import BLOCK_ELEMS  # noqa: E402
-from repro.core.tier import BitplaneLayout, make_device  # noqa: E402
+from repro.core.tier import BitplaneLayout  # noqa: E402
 from repro.kernels import dispatch  # noqa: E402
 from repro.kernels.bitplane import pack_planes_slab  # noqa: E402
 from repro.kernels.lz4 import match_events_slab  # noqa: E402
 from repro.kernels.pnm_score import page_scores_u16  # noqa: E402
 from repro.launch.serve import serve, serve_continuous  # noqa: E402
 from repro.models import init_cache, init_params  # noqa: E402
-from repro.runtime import ServeEngine  # noqa: E402
-from repro.runtime.paging import LOSSLESS_POLICY  # noqa: E402
 from repro.runtime.serving import _jit_step  # noqa: E402
 
 ARCH = "qwen2-0.5b"
 PAGE_TOKENS = 16
 KERNELS = ("pack", "lz4", "score")
-_COMPILES = []
-
-
-def _count_compiles(event: str, duration: float, **_):
-    if event == "/jax/core/compile/backend_compile_duration":
-        _COMPILES.append(duration)
 
 
 def model(smoke: bool, seed: int):
@@ -171,62 +161,18 @@ def phase_schedule(smoke: bool, num_requests: int, prompt_len: int,
           f"{d.dram_bytes_stored} B in {d.blocks} blocks")
 
 
-def _timed(fn):
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn())
-    return time.perf_counter() - t0, out
-
-
-def phase_measure(cfg, params, prompt_len: int, n_tokens: int, batch: int,
-                  seed: int) -> dict:
-    """Wall times on the host clock, each ending in block_until_ready:
-    the bare jitted model step, and the engine's step with the tier."""
-    rng = np.random.default_rng(seed)
-    prompt = rng.integers(0, cfg.vocab, (batch, prompt_len)).astype(np.int32)
-    max_seq = prompt_len + n_tokens + PAGE_TOKENS
-
-    cache = init_cache(cfg, batch, max_seq)
-    step = {"tokens": jnp.asarray(prompt), "cache_pos": jnp.int32(0)}
-    t_model_prefill, (_, cache) = _timed(
-        lambda: _jit_step(cfg, params, step, cache))
-    model_decode = []
-    for i in range(n_tokens):
-        step = {"tokens": jnp.asarray(prompt[:, :1]),
-                "cache_pos": jnp.int32(prompt_len + i)}
-        dt, (_, cache) = _timed(lambda: _jit_step(cfg, params, step, cache))
-        model_decode.append(dt)
-
-    eng = ServeEngine(cfg, params, max_seq=max_seq, batch=batch,
-                      page_tokens=PAGE_TOKENS, hbm_kv_budget=1 << 12,
-                      device_kind=make_device("trace", shards=1),
-                      policy=LOSSLESS_POLICY)
-    t_prefill, (logits, _) = _timed(lambda: (eng.prefill(prompt), eng.cache))
-    serve_decode = []
-    for _ in range(n_tokens):
-        assert np.isfinite(logits).all(), "logits are not finite"
-        nxt = logits.argmax(-1).astype(np.int32).reshape(-1, 1)
-        dt, (logits, _) = _timed(lambda: (eng.decode(nxt), eng.cache))
-        serve_decode.append(dt)
-    assert np.isfinite(logits).all(), "logits are not finite"
-    return {
-        "measured_model_prefill_s": t_model_prefill,
-        "measured_model_decode_step_s_p50": statistics.median(model_decode),
-        "measured_prefill_s": t_prefill,
-        "measured_decode_step_s_p50": statistics.median(serve_decode),
-        "measured_decode_step_s_max": max(serve_decode),
-        "prompt_tokens": prompt_len * batch,
-        "decode_steps": n_tokens,
-    }
-
-
-def _check_device_paths(calls) -> None:
-    """Every tier encode and gather took the compiled kernel."""
-    other = {k: v for k, v in calls.items() if k[1] != dispatch.DEVICE}
+def _check_device_paths(counters: dict) -> None:
+    """Every tier encode and gather took the compiled kernel, by the
+    ``kernel.<kernel>.<path>`` counters of ``repro.obs``."""
+    calls = {k: n for k, n in counters.items() if k.startswith("kernel.")}
+    other = {k: n for k, n in calls.items()
+             if not k.endswith(f".{dispatch.DEVICE}")}
     assert not other, f"tier calls off the device path: {other}"
-    missing = [k for k in KERNELS if not calls[(k, dispatch.DEVICE)]]
+    on = {k: calls.get(f"kernel.{k}.{dispatch.DEVICE}", 0) for k in KERNELS}
+    missing = [k for k, n in on.items() if not n]
     assert not missing, f"no device call of {missing}"
     print("[guard] tier kernel calls, all on the device path: "
-          + ", ".join(f"{k}={calls[(k, dispatch.DEVICE)]}" for k in KERNELS))
+          + ", ".join(f"{k}={n}" for k, n in on.items()))
 
 
 def main(argv=None) -> int:
@@ -245,34 +191,20 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     cache_dir = enable_compile_cache()
-    jax.monitoring.register_event_duration_secs_listener(_count_compiles)
     print(f"[device] kind={devs[0].device_kind} count={len(devs)} "
           f"compile_cache={cache_dir}")
     assert not codec._scalar_lz4_forced(), "TRACE_SCALAR_LZ4 pins the oracle"
 
-    t0 = time.perf_counter()
     cfg, params = model(smoke=False, seed=args.seed)
     print(f"[model] {ARCH}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
           f"vocab {cfg.vocab}, {cfg.n_kv_heads} kv heads")
     phase_parity(kv_pages(cfg, params, args.prompt_len, args.tokens,
                           args.batch, args.seed))
-    dispatch.CALLS.clear()
+    obs.reset()
     phase_serve(False, args.prompt_len, args.tokens, args.batch, args.seed)
     phase_schedule(False, args.requests, args.prompt_len, args.tokens,
                    args.batch, args.seed)
-    n_compiles = len(_COMPILES)
-    m = phase_measure(cfg, params, args.prompt_len, args.tokens, args.batch,
-                      args.seed)
-    _check_device_paths(dispatch.CALLS)
-    stats = devs[0].memory_stats() or {}
-    m.update(
-        xla_compiles=len(_COMPILES),
-        xla_compiles_in_measure=len(_COMPILES) - n_compiles,
-        xla_compile_s=sum(_COMPILES),
-        peak_bytes_in_use=stats.get("peak_bytes_in_use"),
-        wall_s=time.perf_counter() - t0,
-    )
-    print("[measure] " + json.dumps(m))
+    _check_device_paths(obs.snapshot()["counters"])
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

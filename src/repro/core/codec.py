@@ -44,6 +44,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
+
 try:
     import zstandard as _zstd
 
@@ -545,9 +547,11 @@ def _lz4_slab_streams(buf: np.ndarray, starts: np.ndarray,
         buf = np.concatenate([buf[a:b] for a, b in zip(starts, ends)])
         ends = np.cumsum(sizes)
         starts = ends - sizes
-    pos, dist, mlen = _lz4_kernel().match_events_slab(buf, starts, ends,
-                                                      force=force)
-    return lz4_emit_events(buf, starts, ends, pos, dist, mlen)
+    with obs.span("tier.lz4_match"):
+        pos, dist, mlen = _lz4_kernel().match_events_slab(buf, starts, ends,
+                                                          force=force)
+    with obs.span("tier.lz4_emit"):
+        return lz4_emit_events(buf, starts, ends, pos, dist, mlen)
 
 
 def lz4_compress_batch(chunks: Sequence[bytes],
@@ -902,7 +906,9 @@ def compress_slab(slab, starts: Sequence[int], ends: Sequence[int],
     payloads: List[bytes] = [b""] * S
     flags: List[int] = [RAW] * S
     todo: List[int] = []
-    for i, skip in enumerate(_prescreen_slab(buf, starts, ends)):
+    with obs.span("tier.prescreen"):
+        skips = _prescreen_slab(buf, starts, ends)
+    for i, skip in enumerate(skips):
         if skip:
             payloads[i] = buf[starts[i]: ends[i]].tobytes()
         else:

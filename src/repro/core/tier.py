@@ -133,6 +133,7 @@ from typing import (
 
 import numpy as np
 
+from .. import obs
 from . import codec as codecs
 from .bitplane import (
     BF16_BITS,
@@ -900,8 +901,9 @@ class BitplaneLayout(Layout):
         from ..kernels.bitplane import pack_planes_slab
 
         sizes = [c.size for c in chunks]
-        planes = pack_planes_slab(np.concatenate(chunks) if len(chunks) > 1
-                                  else chunks[0].ravel())
+        with obs.span("tier.pack"):
+            planes = pack_planes_slab(np.concatenate(chunks)
+                                      if len(chunks) > 1 else chunks[0].ravel())
         offs = np.cumsum([0] + [n // 8 for n in sizes])
         nblk = len(chunks)
         n8 = planes.shape[1]
@@ -1522,17 +1524,19 @@ class TierStore:
         pack, codec — and commit blocks in staging order."""
         if not slab.chunks:
             return
-        self._transform_kv_windows(slab)
-        enc = (self.layout.encode_batch if self.batched_encode
-               else self.layout.encode_batch_scalar)
-        encoded = enc(slab.chunks, self.codec)
-        for (payloads, flags), key, rec, chunk, valid, meta in zip(
-                encoded, slab.keys, slab.recs, slab.chunks, slab.valids,
-                slab.metas):
-            self._commit(rec, key,
-                         _Block(payloads, flags, valid, chunk.size,
-                                kv_meta=meta))
-        slab.clear()
+        with obs.span("tier.encode"):
+            with obs.span("tier.kv_transform"):
+                self._transform_kv_windows(slab)
+            enc = (self.layout.encode_batch if self.batched_encode
+                   else self.layout.encode_batch_scalar)
+            encoded = enc(slab.chunks, self.codec)
+            for (payloads, flags), key, rec, chunk, valid, meta in zip(
+                    encoded, slab.keys, slab.recs, slab.chunks, slab.valids,
+                    slab.metas):
+                self._commit(rec, key,
+                             _Block(payloads, flags, valid, chunk.size,
+                                    kv_meta=meta))
+            slab.clear()
 
     def _transform_kv_windows(self, slab: "_EncodeSlab"):
         """Resolve deferred KV windows into transformed chunks + metas.
@@ -1616,7 +1620,8 @@ class TierStore:
                                         [recs[i] for i in read_ix])
             for i, req in enumerate(reqs):
                 if isinstance(req, GatherReq):
-                    self._do_gather(req, recs[i])
+                    with obs.span("tier.gather"):
+                        self._do_gather(req, recs[i])
             return recs
         finally:
             for rec in recs:
@@ -1667,10 +1672,11 @@ class TierStore:
             for eff, blocks in zip(per_key_views, per_key_blocks):
                 for v, b in zip(eff, blocks):
                     groups.setdefault(v, []).append(b)
-            decoded = {
-                v: iter(self.layout.decode_batch(blocks, v, self.codec))
-                for v, blocks in groups.items()
-            }
+            with obs.span("tier.decode"):
+                decoded = {
+                    v: iter(self.layout.decode_batch(blocks, v, self.codec))
+                    for v, blocks in groups.items()
+                }
             out = []
             for key, eff in zip(keys, per_key_views):
                 segs = [next(decoded[v]) for v in eff]
@@ -1746,10 +1752,11 @@ class TierStore:
         for views, blocks in zip(req_views, req_blocks):
             for view, b in zip(views, blocks):
                 groups.setdefault(view, []).append(b)
-        decoded = {
-            view: iter(self.layout.decode_batch(blocks, view, self.codec))
-            for view, blocks in groups.items()
-        }
+        with obs.span("tier.decode"):
+            decoded = {
+                view: iter(self.layout.decode_batch(blocks, view, self.codec))
+                for view, blocks in groups.items()
+            }
 
         out: List[Receipt] = []
         for req, rec, blocks, views in zip(reqs, recs, req_blocks,

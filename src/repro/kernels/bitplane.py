@@ -144,6 +144,7 @@ def pack_planes_pallas(x_u16: jnp.ndarray, block_r: int = DEFAULT_BLOCK_R,
         out_specs=pl.BlockSpec((BF16_BITS, br, c8), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BF16_BITS, R, c8), jnp.uint8),
         interpret=interpret,
+        name="pack_planes_pallas",   # the trace's op name, as metrics read it
     )(xt)
 
 

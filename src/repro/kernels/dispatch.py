@@ -12,22 +12,19 @@ call:
   off the TPU — the parity tests use that.
 
 Backend errors propagate: a broken runtime is never read as "cpu".
-:data:`CALLS` counts every decision by ``(kernel, path)`` so a caller can
-prove which path a run took.
+Every decision adds one to the ``repro.obs`` counter
+``kernel.<kernel>.<path>``, so a caller can prove which path a run took.
 """
 
 from __future__ import annotations
 
-import collections
-
 import jax
+
+from .. import obs
 
 DEVICE = "device"          # compiled for the TPU
 INTERPRET = "interpret"    # the same kernel, pallas interpret mode
 NUMPY = "numpy"            # the numpy twin
-
-#: ``(kernel, path) -> calls`` since import (or the caller's last clear).
-CALLS: collections.Counter = collections.Counter()
 
 
 def choose(kernel: str, force: str | None = None) -> str:
@@ -39,7 +36,7 @@ def choose(kernel: str, force: str | None = None) -> str:
         path = NUMPY
     else:
         path = DEVICE if tpu else INTERPRET
-    CALLS[(kernel, path)] += 1
+    obs.count(f"kernel.{kernel}.{path}")
     return path
 
 

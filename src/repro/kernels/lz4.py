@@ -504,6 +504,7 @@ def _prep_pallas(buf, interpret: bool):
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((rows, _PREP_C), jnp.int32)] * 3,
         interpret=interpret,
+        name="lz4_prep_pallas",
     )(*shifted)
     return (w.reshape(-1)[:n], h.reshape(-1)[:n], runb.reshape(-1)[:n])
 
@@ -563,38 +564,45 @@ def _suffix_min(x, fill: int):
 @functools.partial(jax.jit, static_argnames=("L", "E", "interpret"))
 def _device_match(slab, starts, sizes, row_start, *, L: int, E: int,
                   interpret: bool):
+    # the named scopes label each pass's ops in a profiler trace
     S = starts.shape[0]
     o = lax.broadcasted_iota(jnp.int32, (S, L), 1)          # row offset
-    src = jnp.clip(starts[:, None] + o, 0, slab.shape[0] - 1)
-    inside = o < sizes[:, None]
-    buf = jnp.where(inside, slab[src], 0).astype(jnp.uint8).reshape(-1)
-    N = S * L
-    w, h, runb = _prep_pallas(buf, interpret)
+    with jax.named_scope("lz4_prep"):
+        src = jnp.clip(starts[:, None] + o, 0, slab.shape[0] - 1)
+        inside = o < sizes[:, None]
+        buf = jnp.where(inside, slab[src], 0).astype(jnp.uint8).reshape(-1)
+        N = S * L
+        w, h, runb = _prep_pallas(buf, interpret)
     iota = jnp.arange(N, dtype=jnp.int32)
     sid = iota // L
     valid = o + MIN_MATCH <= sizes[:, None]
     start_ok = (valid & (o < sizes[:, None] - MFLIMIT)).reshape(-1)
     stride_ok = ((o >= 2) & (o % RUN_STRIDE != 0)).reshape(-1)
-    # stable sort of each row's hashes: the previous same-hash occurrence
-    # is the sorted neighbour — the stream's whole hash table in one pass
-    keys = jnp.where(valid, h.reshape(S, L), HASH_SIZE)
-    sk, so = lax.sort((keys, o), dimension=1, is_stable=True, num_keys=1)
-    same = (sk[:, 1:] == sk[:, :-1]) & (sk[:, 1:] < HASH_SIZE)
-    rows = lax.broadcasted_iota(jnp.int32, (S, L - 1), 0)
-    prev = jnp.full((S, L), -1, dtype=jnp.int32).at[rows, so[:, 1:]].set(
-        jnp.where(same, rows * L + so[:, :-1], -1)).reshape(-1)
+    with jax.named_scope("lz4_hash_sort"):
+        # stable sort of each row's hashes: the previous same-hash
+        # occurrence is the sorted neighbour — the stream's whole hash
+        # table in one pass
+        keys = jnp.where(valid, h.reshape(S, L), HASH_SIZE)
+        sk, so = lax.sort((keys, o), dimension=1, is_stable=True, num_keys=1)
+        same = (sk[:, 1:] == sk[:, :-1]) & (sk[:, 1:] < HASH_SIZE)
+        rows = lax.broadcasted_iota(jnp.int32, (S, L - 1), 0)
+        prev = jnp.full((S, L), -1, dtype=jnp.int32).at[rows, so[:, 1:]].set(
+            jnp.where(same, rows * L + so[:, :-1], -1)).reshape(-1)
     dist = iota - prev
     bufm2 = jnp.concatenate([jnp.zeros(2, buf.dtype), buf[:-2]])
     bufm1 = jnp.concatenate([jnp.zeros(1, buf.dtype), buf[:-1]])
     ok = (start_ok & (prev >= 0) & (dist <= 0xFFFF)
           & (w == w[jnp.clip(prev, 0, N - 1)]))
     ok &= ~((dist == 1) & stride_ok & (bufm2 == bufm1))
-    # next candidate at or after each position, and the last byte of the
-    # byte run through it (every run length is capped inside its stream)
-    ncand = _suffix_min(jnp.where(ok, iota, N).reshape(S, L), N).reshape(-1)
-    run_last = _suffix_min(
-        jnp.where(runb > 0, iota, iota | (L - 1)).reshape(S, L),
-        N).reshape(-1)
+    with jax.named_scope("lz4_suffix_scans"):
+        # next candidate at or after each position, and the last byte of
+        # the byte run through it (every run length is capped inside its
+        # stream)
+        ncand = _suffix_min(jnp.where(ok, iota, N).reshape(S, L),
+                            N).reshape(-1)
+        run_last = _suffix_min(
+            jnp.where(runb > 0, iota, iota | (L - 1)).reshape(S, L),
+            N).reshape(-1)
 
     sids = jnp.arange(S, dtype=jnp.int32)
     max_end = sids * L + sizes - LAST_LITERALS
@@ -652,6 +660,7 @@ def _device_match(slab, starts, sizes, row_start, *, L: int, E: int,
         return (ncur, count, out, nlive & live)
 
     out0 = tuple(jnp.zeros(E + 1, jnp.int32) for _ in range(3))
-    _, count, out, _ = lax.while_loop(
-        cond, body, (cur0, jnp.zeros(S, jnp.int32), out0, live0))
+    with jax.named_scope("lz4_cursor_loop"):
+        _, count, out, _ = lax.while_loop(
+            cond, body, (cur0, jnp.zeros(S, jnp.int32), out0, live0))
     return out[0][:E], out[1][:E], out[2][:E], count

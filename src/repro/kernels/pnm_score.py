@@ -115,6 +115,7 @@ def page_scores_pallas(padded: jnp.ndarray, valid: jnp.ndarray,
         out_specs=pl.BlockSpec((_PAGE_BLOCK, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pp, _LANES), jnp.float32),
         interpret=interpret,
+        name="page_scores_pallas",
     )(jnp.pad(digest.astype(jnp.float32), (0, cp - C)),
       jnp.pad(valid.astype(jnp.int32), (0, pp - P)).reshape(pp, 1),
       pages)

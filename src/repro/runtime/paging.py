@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.precision import FULL, MAN0, MAN2, MAN4, PrecisionView
 from ..core.tier import (
     GatherReq, KV, ReadReq, Receipt, Ticket, TierStore, WriteReq, make_device,
@@ -323,26 +324,27 @@ class KVPagePool:
         hold), but the spill write is elided when an identical page is
         already stored — the pool acquires a ledger reference instead.
         """
-        self._commit_clock += 1
-        for entry in pages:
-            layer, kind, start, tokens_u16, importance = entry[:5]
-            share_hash = entry[5] if len(entry) > 5 else None
-            if share_hash is not None and self.prefix_index is not None:
-                key = shared_page_key(share_hash, layer, kind)
-            else:
-                share_hash = None
-                key = f"{self.key_prefix}L{layer}.{kind}.{start}"
-            page = _Page(key, layer, kind, start, tokens_u16.shape[0],
-                         importance=importance,
-                         commit_seq=self._commit_clock,
-                         share_hash=share_hash)
-            # Always admit to HBM first, then evict the least-important
-            # pages (possibly this one) — importance, not arrival order,
-            # decides residency (paper §II-C: importance is long-tailed).
-            page.resident = tokens_u16.copy()
-            self._hbm_used += tokens_u16.size * 2
-            self._pages.append(page)
-        self._rebalance()
+        with obs.span("commit.append"):
+            self._commit_clock += 1
+            for entry in pages:
+                layer, kind, start, tokens_u16, importance = entry[:5]
+                share_hash = entry[5] if len(entry) > 5 else None
+                if share_hash is not None and self.prefix_index is not None:
+                    key = shared_page_key(share_hash, layer, kind)
+                else:
+                    share_hash = None
+                    key = f"{self.key_prefix}L{layer}.{kind}.{start}"
+                page = _Page(key, layer, kind, start, tokens_u16.shape[0],
+                             importance=importance,
+                             commit_seq=self._commit_clock,
+                             share_hash=share_hash)
+                # Always admit to HBM first, then evict the least-important
+                # pages (possibly this one) — importance, not arrival order,
+                # decides residency (paper §II-C: importance is long-tailed).
+                page.resident = tokens_u16.copy()
+                self._hbm_used += tokens_u16.size * 2
+                self._pages.append(page)
+            self._rebalance()
 
     def _rebalance(self):
         """Evict the least-important resident pages when over budget."""
@@ -660,35 +662,36 @@ class KVPagePool:
         and discarded: its data predates the degrade, and serving it
         would break the degraded-decode differential.
         """
-        ladder = (self.degrade_ladder if ladder is None else tuple(ladder))
-        if target_bytes <= 0 or not ladder:
-            return 0
-        cold = sorted(
-            (p for p in self._pages if p.resident is None),
-            key=lambda p: (p.commit_seq, p.importance, p.start),
-        )
-        freed = 0
-        for level, view in enumerate(ladder):
-            for page in cold:
-                if freed >= target_bytes:
-                    return freed
-                if page.degrade_level >= level:
-                    continue
-                if page.shared_ref:
-                    continue
-                try:
-                    freed += self.device.truncate_planes([page.key], view)
-                except NotImplementedError:
-                    return freed        # word layout: nothing to shed
-                page.degrade_level = level
-                # truncate_planes drained the queue, so a prefetch issued
-                # earlier has executed against the PRE-truncation planes;
-                # account it, then drop it so read_layer re-reads the
-                # degraded state instead of serving stale full precision.
-                pf = self._prefetched.pop(page.key, None)
-                if pf is not None:
-                    self._settle_prefetch(pf)
-        return freed
+        with obs.span("pool.reclaim"):
+            ladder = (self.degrade_ladder if ladder is None else tuple(ladder))
+            if target_bytes <= 0 or not ladder:
+                return 0
+            cold = sorted(
+                (p for p in self._pages if p.resident is None),
+                key=lambda p: (p.commit_seq, p.importance, p.start),
+            )
+            freed = 0
+            for level, view in enumerate(ladder):
+                for page in cold:
+                    if freed >= target_bytes:
+                        return freed
+                    if page.degrade_level >= level:
+                        continue
+                    if page.shared_ref:
+                        continue
+                    try:
+                        freed += self.device.truncate_planes([page.key], view)
+                    except NotImplementedError:
+                        return freed        # word layout: nothing to shed
+                    page.degrade_level = level
+                    # truncate_planes drained the queue, so a prefetch issued
+                    # earlier has executed against the PRE-truncation planes;
+                    # account it, then drop it so read_layer re-reads the
+                    # degraded state instead of serving stale full precision.
+                    pf = self._prefetched.pop(page.key, None)
+                    if pf is not None:
+                        self._settle_prefetch(pf)
+            return freed
 
     # -- teardown ---------------------------------------------------------------
     def release(self) -> int:
@@ -714,26 +717,27 @@ class KVPagePool:
         copy survives as long as any other request still refers to it,
         and frees with the last retirement.
         """
-        for entry in self._prefetched.values():
-            self._settle_prefetch(entry)
-        self._prefetched.clear()
-        freed = 0
-        for p in self._pages:
-            if p.shared_ref:
-                self.prefix_index.release(p.key)
-                p.shared_ref = False
-                freed += 1
-        if self.key_prefix:
-            freed += self.device.delete_prefix(self.key_prefix)
-        else:
-            keys = {p.key for p in self._pages if not p.share_hash}
-            for k in keys:
-                self.device.delete(k)
-            freed += len(keys)
-        self._pages.clear()
-        self.spill_events.clear()
-        self._hbm_used = 0
-        return freed
+        with obs.span("pool.release"):
+            for entry in self._prefetched.values():
+                self._settle_prefetch(entry)
+            self._prefetched.clear()
+            freed = 0
+            for p in self._pages:
+                if p.shared_ref:
+                    self.prefix_index.release(p.key)
+                    p.shared_ref = False
+                    freed += 1
+            if self.key_prefix:
+                freed += self.device.delete_prefix(self.key_prefix)
+            else:
+                keys = {p.key for p in self._pages if not p.share_hash}
+                for k in keys:
+                    self.device.delete(k)
+                freed += len(keys)
+            self._pages.clear()
+            self.spill_events.clear()
+            self._hbm_used = 0
+            return freed
 
     # -- accounting ---------------------------------------------------------------
     @property

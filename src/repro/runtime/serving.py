@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs.base import ArchConfig
 from ..core.system_model import SystemSpec
 from ..core.tier import Ticket, TierStore, make_device
@@ -164,53 +165,64 @@ class ServeEngine:
         self._inflight_gathers: List[Tuple[List[_Page], Ticket]] = []
         self._decode = lambda p, b, c: _jit_step(cfg, p, b, c)
         self._prefill = self._decode
+        # the request tag of this engine's spans (``obs.span``'s ``req``):
+        # its key namespace, ``r<id>`` under the scheduler
+        self.tag = key_prefix.rstrip(".") or None
 
     # -- helpers ---------------------------------------------------------------
     def _commit_pages(self, lo: int, hi: int):
         """Push completed KV windows [lo, hi) into the page pool."""
-        # Tickets issued at the previous boundary were in flight across the
-        # decode step that just ran — apply their data before committing.
-        self.flush_io()
-        layers = self.cache.get("layers", {})
-        kv_keys = [k for k in ("k", "v", "c_kv") if k in layers]
-        if not kv_keys:
-            return  # SSM/hybrid: constant-size state, nothing paged
-        # Gather every completed window across layers and kinds into one
-        # batched admission: the spill this triggers goes to the device as
-        # one write batch → one vectorized encode slab, instead of a
-        # per-page pack+codec pipeline.
-        batch_pages = []
-        for start in range(lo - lo % self.page_tokens, hi, self.page_tokens):
-            if start + self.page_tokens > hi:
-                break
-            for kind in kv_keys:
-                buf = np.asarray(layers[kind])  # (L, B, S, ...) bf16
-                n_layers = buf.shape[0]
-                # Windows fully inside the prompt carry their prefix
-                # chain hash: identical prompt prefixes produce identical
-                # KV there (causal attention), so these pages are the
-                # shareable ones.  Windows touching generated tokens stay
-                # private — that is the copy-on-write divergence point.
-                share = None
-                if (self._share_hashes
-                        and start + self.page_tokens <= self._prompt_len):
-                    share = self._share_hashes[start // self.page_tokens]
-                for layer in range(n_layers):
-                    page = buf[layer, :, start : start + self.page_tokens]
-                    tok = page.reshape(self.page_tokens * self.batch, -1)
-                    u16 = np.ascontiguousarray(tok).view(np.uint16)
-                    # recency as default importance; importance="attention"
-                    # replaces it below with accumulated attention mass and
-                    # keeps re-ranking live pages via
-                    # pool.update_importance every boundary
-                    batch_pages.append(
-                        (layer, kind, start, u16, float(start), share)
-                    )
-        if batch_pages:
-            if self.importance == "attention":
-                batch_pages = self._apply_attention_importance(batch_pages)
-            self.pool.append_pages(batch_pages)
-        self._issue_readback()
+        with obs.span("commit.pages", self.tag):
+            # Tickets issued at the previous boundary were in flight across the
+            # decode step that just ran — apply their data before committing.
+            self.flush_io()
+            layers = self.cache.get("layers", {})
+            kv_keys = [k for k in ("k", "v", "c_kv") if k in layers]
+            if not kv_keys:
+                return  # SSM/hybrid: constant-size state, nothing paged
+            pt = self.page_tokens
+            starts = [s for s in range(lo - lo % pt, hi, pt) if s + pt <= hi]
+            bufs = {}
+            if starts:
+                with obs.span("commit.pull"):
+                    for kind in kv_keys:
+                        # (L, B, S, ...) bf16
+                        bufs[kind] = np.asarray(layers[kind])
+                        obs.count("commit.d2h_bytes", bufs[kind].nbytes)
+            # Gather every completed window across layers and kinds into one
+            # batched admission: the spill this triggers goes to the device as
+            # one write batch → one vectorized encode slab, instead of a
+            # per-page pack+codec pipeline.
+            batch_pages = []
+            for start in starts:
+                for kind in kv_keys:
+                    buf = bufs[kind]
+                    n_layers = buf.shape[0]
+                    # Windows fully inside the prompt carry their prefix
+                    # chain hash: identical prompt prefixes produce identical
+                    # KV there (causal attention), so these pages are the
+                    # shareable ones.  Windows touching generated tokens stay
+                    # private — that is the copy-on-write divergence point.
+                    share = None
+                    if (self._share_hashes
+                            and start + self.page_tokens <= self._prompt_len):
+                        share = self._share_hashes[start // self.page_tokens]
+                    for layer in range(n_layers):
+                        page = buf[layer, :, start : start + self.page_tokens]
+                        tok = page.reshape(self.page_tokens * self.batch, -1)
+                        u16 = np.ascontiguousarray(tok).view(np.uint16)
+                        # recency as default importance; importance="attention"
+                        # replaces it below with accumulated attention mass and
+                        # keeps re-ranking live pages via
+                        # pool.update_importance every boundary
+                        batch_pages.append(
+                            (layer, kind, start, u16, float(start), share)
+                        )
+            if batch_pages:
+                if self.importance == "attention":
+                    batch_pages = self._apply_attention_importance(batch_pages)
+                self.pool.append_pages(batch_pages)
+            self._issue_readback()
 
     def _issue_readback(self):
         """Start spill readback for this boundary's evictions.
@@ -224,15 +236,16 @@ class ServeEngine:
         events, self.pool.spill_events = self.pool.spill_events, []
         if not events:
             return
-        if self.pnm_topk is not None:
-            self._issue_gather(events)
-            return
-        if self.async_io:
-            self._inflight.extend(
-                zip(events, self.pool.read_pages_async(events))
-            )
-        else:
-            self._apply_readback(events, self.pool.read_pages(events))
+        with obs.span("commit.issue"):
+            if self.pnm_topk is not None:
+                self._issue_gather(events)
+                return
+            if self.async_io:
+                self._inflight.extend(
+                    zip(events, self.pool.read_pages_async(events))
+                )
+            else:
+                self._apply_readback(events, self.pool.read_pages(events))
 
     def _issue_gather(self, events: Sequence[_Page]):
         """PNM read mode: replace the boundary's full spill readback with
@@ -268,10 +281,12 @@ class ServeEngine:
         gathers, self._inflight_gathers = self._inflight_gathers, []
         if inflight:
             pages = [p for p, _ in inflight]
-            data = self.pool.drain_reads([t for _, t in inflight])
+            with obs.span("commit.drain", self.tag):
+                data = self.pool.drain_reads([t for _, t in inflight])
             self._apply_readback(pages, data)
         for cands, ticket in gathers:
-            winners, data = self.pool.drain_gather(cands, ticket)
+            with obs.span("commit.drain", self.tag):
+                winners, data = self.pool.drain_gather(cands, ticket)
             self._apply_readback(winners, data)
 
     def _query_digest(self, kind: str) -> np.ndarray:
@@ -364,22 +379,29 @@ class ServeEngine:
         batch (vectorized plane decode on the device side)."""
         import ml_dtypes
 
-        layers = dict(self.cache["layers"])
-        touched = False
-        for page, u16 in zip(pages, data):
-            buf = np.asarray(layers[page.kind])
-            target = buf[page.layer][:, page.start : page.start + self.page_tokens]
-            vals = u16.view(ml_dtypes.bfloat16).reshape(target.shape)
-            buf = buf.copy()
-            buf[page.layer][:, page.start : page.start + self.page_tokens] = vals
-            layers[page.kind] = buf
-            touched = True
-        if touched:
-            self.cache = dict(self.cache)
-            self.cache["layers"] = {
-                k: jnp.asarray(v) if isinstance(v, np.ndarray) else v
-                for k, v in layers.items()
-            }
+        with obs.span("commit.apply", self.tag):
+            layers = dict(self.cache["layers"])
+            touched = False
+            for page, u16 in zip(pages, data):
+                buf = layers[page.kind]
+                if not isinstance(buf, np.ndarray):
+                    buf = np.asarray(buf)
+                    obs.count("commit.d2h_bytes", buf.nbytes)
+                target = buf[page.layer][:, page.start : page.start + self.page_tokens]
+                vals = u16.view(ml_dtypes.bfloat16).reshape(target.shape)
+                buf = buf.copy()
+                obs.count("commit.copy_bytes", buf.nbytes)
+                buf[page.layer][:, page.start : page.start + self.page_tokens] = vals
+                layers[page.kind] = buf
+                touched = True
+            if touched:
+                self.cache = dict(self.cache)
+                self.cache["layers"] = {}
+                for k, v in layers.items():
+                    if isinstance(v, np.ndarray):
+                        obs.count("commit.h2d_bytes", v.nbytes)
+                        v = jnp.asarray(v)
+                    self.cache["layers"][k] = v
 
     # -- API ---------------------------------------------------------------------
     def prefill(self, tokens: np.ndarray) -> np.ndarray:
@@ -390,14 +412,16 @@ class ServeEngine:
             "tokens": jnp.asarray(tokens),
             "cache_pos": jnp.int32(self.pos),
         }
-        logits, self.cache = self._prefill(self.params, batch, self.cache)
+        with obs.span("model.prefill", self.tag):
+            logits, self.cache = self._prefill(self.params, batch, self.cache)
         old = self.pos
         self.pos += S
         if old == 0 and self.pool.prefix_index is not None:
             self._share_hashes = prefix_chain_hashes(tokens, self.page_tokens)
             self._prompt_len = S
         self._commit_pages(old, self.pos)
-        return np.asarray(logits[:, -1])
+        with obs.span("model.sync", self.tag):
+            return np.asarray(logits[:, -1])
 
     def decode(self, tokens: np.ndarray) -> np.ndarray:
         """tokens: (batch, 1) current token → next-token logits."""
@@ -405,11 +429,13 @@ class ServeEngine:
             "tokens": jnp.asarray(tokens.reshape(self.batch, 1)),
             "cache_pos": jnp.int32(self.pos),
         }
-        logits, self.cache = self._decode(self.params, batch, self.cache)
+        with obs.span("model.decode", self.tag):
+            logits, self.cache = self._decode(self.params, batch, self.cache)
         old = self.pos
         self.pos += 1
         self._commit_pages(old, self.pos)
-        return np.asarray(logits[:, -1])
+        with obs.span("model.sync", self.tag):
+            return np.asarray(logits[:, -1])
 
     def generate(self, prompt: np.ndarray, n_tokens: int,
                  greedy: bool = True, seed: int = 0) -> np.ndarray:
@@ -1027,22 +1053,24 @@ class ServeScheduler:
         delete) runs BEFORE the tick's time advance, so retirement I/O is
         priced into the same tick — including the run's final tick, which
         has no later tick to absorb it."""
-        self._admit()
-        self.peak_active = max(self.peak_active, self.n_active)
-        self._decode_round()
-        for seq in self.active:
-            if seq is not None and seq.done:
-                seq.engine.retire()
-        self._advance_time()
-        # First-token stamps land after the tick's time advance: TTFT
-        # includes the round that actually produced the token.
-        for rec in self._first_this_tick:
-            rec.first_token_step = self.clock
-            rec.t_first_token_s = self.model_time_s
-        self._first_this_tick.clear()
-        self._update_ratio()
-        self._retire()
-        self.clock += 1
+        with obs.tick(self.clock):
+            self._admit()
+            self.peak_active = max(self.peak_active, self.n_active)
+            self._decode_round()
+            for seq in self.active:
+                if seq is not None and seq.done:
+                    seq.engine.retire()
+            self._advance_time()
+            # First-token stamps land after the tick's time advance: TTFT
+            # includes the round that actually produced the token.
+            for rec in self._first_this_tick:
+                rec.first_token_step = self.clock
+                rec.t_first_token_s = self.model_time_s
+            self._first_this_tick.clear()
+            with obs.span("sched.ratio"):
+                self._update_ratio()
+            self._retire()
+            self.clock += 1
         return bool(self.pending or any(s is not None for s in self.active))
 
     def run(self, requests: Optional[Sequence] = None,
@@ -1186,34 +1214,36 @@ class ServeScheduler:
         return self._kv_fits(rec)
 
     def _admit(self):
-        # Stamp every request the trace has delivered by now: queueing
-        # delay starts at arrival, not at admission.
-        for r in self.pending:
-            if r.arrival > self.clock:
-                break
-            rec = self.records[r.req_id]
-            if rec.t_arrive_s < 0:
-                rec.t_arrive_s = self.model_time_s
-        free = [i for i, s in enumerate(self.active) if s is None]
-        while free and self.pending and self.pending[0].arrival <= self.clock:
-            req = self.pending[0]
-            rec = self.records[req.req_id]
-            rec.kv_novel_bytes = self._novel_bytes(req, rec)
-            if not self._kv_fits(rec) and not self._reclaim_for(rec):
-                break                    # strict FIFO: wait for retirements
-            self.pending.pop(0)
-            self.kv_committed_bytes += rec.kv_charged_bytes
-            self.active[free.pop(0)] = self._start(req, rec)
+        with obs.span("sched.admit"):
+            # Stamp every request the trace has delivered by now: queueing
+            # delay starts at arrival, not at admission.
+            for r in self.pending:
+                if r.arrival > self.clock:
+                    break
+                rec = self.records[r.req_id]
+                if rec.t_arrive_s < 0:
+                    rec.t_arrive_s = self.model_time_s
+            free = [i for i, s in enumerate(self.active) if s is None]
+            while free and self.pending and self.pending[0].arrival <= self.clock:
+                req = self.pending[0]
+                rec = self.records[req.req_id]
+                rec.kv_novel_bytes = self._novel_bytes(req, rec)
+                if not self._kv_fits(rec) and not self._reclaim_for(rec):
+                    break                    # strict FIFO: wait for retirements
+                self.pending.pop(0)
+                self.kv_committed_bytes += rec.kv_charged_bytes
+                self.active[free.pop(0)] = self._start(req, rec)
 
     def _start(self, req: ServeRequest, rec: RequestRecord) -> _ActiveSeq:
-        eng = ServeEngine(
-            self.cfg, self.params, max_seq=self._max_seq, batch=self.batch,
-            page_tokens=self.page_tokens, hbm_kv_budget=self.hbm_kv_budget,
-            device_kind=self.device, policy=self.policy,
-            key_prefix=f"r{req.req_id}.", async_io=self.async_io,
-            prefix_index=self.prefix_index,
-            pnm_topk=self.pnm_topk, importance=self.importance,
-        )
+        with obs.span("sched.engine_init", f"r{req.req_id}"):
+            eng = ServeEngine(
+                self.cfg, self.params, max_seq=self._max_seq, batch=self.batch,
+                page_tokens=self.page_tokens, hbm_kv_budget=self.hbm_kv_budget,
+                device_kind=self.device, policy=self.policy,
+                key_prefix=f"r{req.req_id}.", async_io=self.async_io,
+                prefix_index=self.prefix_index,
+                pnm_topk=self.pnm_topk, importance=self.importance,
+            )
         rec.admit_step = self.clock
         rec.t_admit_s = self.model_time_s
         rec.prefill_tokens = int(req.prompt.size)
@@ -1225,7 +1255,9 @@ class ServeScheduler:
         for seq in self.active:
             if seq is None:
                 continue
-            nxt = _sample_next(seq.logits, seq.rng, seq.req.greedy)
+            with obs.span("sched.sample", seq.engine.tag):
+                nxt = _sample_next(seq.logits, seq.rng, seq.req.greedy)
+            obs.count("sched.tokens", nxt.size)
             seq.out.append(nxt)
             if len(seq.out) == 1:
                 self._first_this_tick.append(seq.record)

@@ -2,7 +2,6 @@
 script cannot rot between chip runs, and its refusal to run without a
 TPU.  Pallas kernels run in interpret mode here."""
 
-import collections
 import subprocess
 import sys
 
@@ -10,6 +9,7 @@ import numpy as np
 import pytest
 
 import chip_smoke as cs
+from repro import obs
 from repro.kernels import dispatch
 
 
@@ -36,29 +36,21 @@ def test_schedule_phase_retires_everything():
                       batch=1, seed=0)
 
 
-def test_measure_phase_reports_measured_times(smoke):
-    cfg, params = smoke
-    m = cs.phase_measure(cfg, params, prompt_len=32, n_tokens=3, batch=2,
-                         seed=0)
-    times = [v for k, v in m.items() if k.startswith("measured_")]
-    assert len(times) == 5 and all(t > 0 for t in times)
-
-
 def test_device_path_guard():
-    calls = collections.Counter({(k, dispatch.DEVICE): 2 for k in cs.KERNELS})
-    cs._check_device_paths(calls)
-    calls[("lz4", dispatch.NUMPY)] += 1
+    counters = {f"kernel.{k}.{dispatch.DEVICE}": 2 for k in cs.KERNELS}
+    cs._check_device_paths(dict(counters, **{"sched.tokens": 9}))
     with pytest.raises(AssertionError, match="off the device path"):
-        cs._check_device_paths(calls)
+        cs._check_device_paths(dict(counters, **{"kernel.lz4.numpy": 1}))
     with pytest.raises(AssertionError, match="no device call"):
-        cs._check_device_paths(collections.Counter({("pack", "device"): 1}))
+        cs._check_device_paths({"kernel.pack.device": 1})
 
 
 def test_tier_takes_numpy_twins_off_the_tpu():
-    dispatch.CALLS.clear()
+    obs.reset()
     cs.phase_serve(True, prompt_len=32, n_tokens=2, batch=1, seed=1)
-    assert dispatch.CALLS[("pack", dispatch.NUMPY)] > 0
-    assert not any(path == dispatch.DEVICE for _, path in dispatch.CALLS)
+    counters = obs.snapshot()["counters"]
+    assert counters[f"kernel.pack.{dispatch.NUMPY}"] > 0
+    assert not any(k.endswith(f".{dispatch.DEVICE}") for k in counters)
 
 
 def test_main_refuses_cpu(capsys):

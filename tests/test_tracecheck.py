@@ -288,6 +288,20 @@ def test_r6_fires_on_module_level_jit_wrap(tmp_path):
     assert [d.rule for d in diags] == ["R6"]
 
 
+def test_r6_fires_on_program_span_in_jit(tmp_path):
+    src = (
+        "import jax\n"
+        "from repro import obs\n"
+        "@jax.jit\n"
+        "def step(x):\n"
+        "    with obs.span('model.decode'):\n"
+        "        return x + 1\n"
+    )
+    diags = _lint(tmp_path, src, [R6JitPurity()])
+    assert [d.rule for d in diags] == ["R6"]
+    assert "obs.span" in diags[0].message
+
+
 def test_r6_untraced_function_clean(tmp_path):
     src = (
         "import numpy as np\n"

@@ -140,6 +140,9 @@ def build_symbol_tables():
                     "tracecheck", "tools.tracecheck", "tools/tracecheck",
                     "TRACE_SANITIZE"))
     strings.update(("recency", "attention"))  # ServeEngine importance modes
+    from repro import obs
+    strings.update(obs.SPAN_NAMES)            # program span and counter names
+    strings.update(obs.COUNTER_NAMES)
     # bench rows gated by absolute floors (tools/bench_diff.py FLOORS)
     strings.update(("lz4_kernel_speedup", "lz4_kernel_byte_identical",
                     "encode_batched_speedup", "shard4_tok_s_gain",

@@ -344,6 +344,8 @@ HOST_SYNC_METHODS = frozenset({"item", "tolist", "block_until_ready"})
 HOST_ARRAY_FNS = frozenset({"asarray", "array", "frombuffer",
                             "ascontiguousarray"})
 NUMPY_ALIASES = frozenset({"np", "numpy"})
+# repro.obs spans: inside a traced body they would time the trace, once
+OBS_SPANS = frozenset({"span", "tick"})
 
 
 def _is_jit_decorator(dec: ast.AST) -> bool:
@@ -422,8 +424,8 @@ class R6JitPurity(Rule):
     id = "R6"
     name = "jit-purity"
     doc = ("no host synchronization (np.asarray, .item(), device_get, "
-           "block_until_ready) or Python-side RNG inside jax.jit / "
-           "pallas_call bodies")
+           "block_until_ready), Python-side RNG or program span "
+           "(obs.span) inside jax.jit / pallas_call bodies")
 
     def check(self, ctx: FileContext) -> Iterator[Diagnostic]:
         for name, (fn, why) in sorted(_traced_functions(ctx.tree).items()):
@@ -454,4 +456,6 @@ class R6JitPurity(Rule):
                 return f"host materialization `{'.'.join(parts)}`"
             if head == "jax" and rest[-1] == "device_get":
                 return "host-sync call `jax.device_get`"
+            if head == "obs" and rest[-1] in OBS_SPANS:
+                return f"program span `{'.'.join(parts)}`"
         return None
