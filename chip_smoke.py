@@ -13,8 +13,9 @@ Phases, in order; any failure exits non-zero:
 
 1. device   — JAX must report a TPU.  There is no CPU fallback.
 2. parity   — on real KV slabs from a full-width prefill, the device
-              paths of the bit-plane pack, the LZ4 match scan and the PNM
-              page scores return the same bytes as their numpy twins.
+              paths of the bit-plane pack, the KV transform with its pack,
+              the LZ4 match scan and the PNM page scores return the same
+              bytes as their numpy twins.
 3. serve    — under the lossless policy the ``trace`` device (pages
               spill through the bit-plane + LZ4 tier) gives the same
               greedy tokens as ``plain``, and a PNM gather with k ≥ the
@@ -48,9 +49,12 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import ARCHS, smoke_config  # noqa: E402
 from repro.core import codec  # noqa: E402
 from repro.core.bitplane import BLOCK_ELEMS  # noqa: E402
+from repro.core.kv_transform import kv_forward_batch  # noqa: E402
 from repro.core.tier import BitplaneLayout  # noqa: E402
 from repro.kernels import dispatch  # noqa: E402
-from repro.kernels.bitplane import pack_planes_slab  # noqa: E402
+from repro.kernels.bitplane import (  # noqa: E402
+    kv_pack_planes_slab, pack_planes_slab,
+)
 from repro.kernels.lz4 import match_events_slab  # noqa: E402
 from repro.kernels.pnm_score import page_scores_u16  # noqa: E402
 from repro.launch.serve import serve, serve_continuous  # noqa: E402
@@ -59,7 +63,7 @@ from repro.runtime.serving import _jit_step  # noqa: E402
 
 ARCH = "qwen2-0.5b"
 PAGE_TOKENS = 16
-KERNELS = ("pack", "lz4", "score")
+KERNELS = ("kv_pack", "lz4", "score")
 
 
 def model(smoke: bool, seed: int):
@@ -100,6 +104,20 @@ def phase_parity(pages) -> None:
     assert dev.dtype == ref.dtype and np.array_equal(dev, ref), "pack differs"
     print(f"[parity] pack: {flat.size} elems -> {ref.shape} planes, "
           f"device == numpy")
+
+    # one slab of the tier's KV windows, transformed and packed
+    windows = [p[i : i + PAGE_TOKENS] for p in pages
+               for i in range(0, p.shape[0], PAGE_TOKENS)]
+    windows = windows[: n // windows[0].size]
+    kv_dev, beta_dev = kv_pack_planes_slab(
+        windows, interpret=jax.default_backend() != "tpu")
+    streams, metas = kv_forward_batch(np.stack(windows))
+    kv_ref = pack_planes_slab(streams.ravel(), force="numpy")
+    assert np.array_equal(kv_dev, kv_ref), "kv pack differs"
+    assert np.array_equal(beta_dev, np.stack([m.beta for m in metas])), \
+        "kv betas differ"
+    print(f"[parity] kv_pack: {len(windows)} windows of "
+          f"{windows[0].shape} -> {kv_ref.shape} planes, device == numpy")
 
     # the tier's streams: one per (plane, block), BLOCK_ELEMS // 8 bytes
     slab = ref.reshape(-1)

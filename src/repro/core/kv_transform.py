@@ -165,7 +165,23 @@ def kv_unpack(planes: np.ndarray, meta: KVBlockMeta) -> np.ndarray:
     return kv_inverse(stream, meta)
 
 
-# -- jnp forward (oracle for the Pallas kernel; beta supplied externally) ----
+# -- jnp forward: the device transform of kv_pack_planes_slab (modal
+# exponents, then the delta), and the oracle for the kv_delta kernel -------
+
+def kv_beta_jnp(windows: jnp.ndarray) -> jnp.ndarray:
+    """(B, n, C) uint16 token-major windows → (B, C) uint8 modal exponents.
+
+    The same value as :func:`kv_forward_batch`'s bincount-argmax, ties
+    included (the smallest exponent among the most frequent), from a
+    pairwise count over each channel's ``n`` tokens — no 256-bin
+    histogram, and the channels stay on the minor axis.
+    """
+    exp = (windows.astype(jnp.int32) >> 7) & 0xFF               # (B, n, C)
+    count = jnp.sum(exp[:, :, None, :] == exp[:, None, :, :], axis=2,
+                    dtype=jnp.int32)
+    best = jnp.max(count * 256 + (255 - exp), axis=1)            # (B, C)
+    return (255 - (best & 0xFF)).astype(jnp.uint8)
+
 
 def kv_forward_jnp(block_u16: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
     """(n, C) uint16 + (C,) uint8 beta → (C, n) transformed uint16 (jnp)."""

@@ -25,11 +25,11 @@ read batch's blocks are grouped by fetched plane-set and decoded in
 vectorized numpy passes — one plane-unpack and one reconstruction call
 per group, not per 4 KB block (see ``BitplaneLayout.decode_batch``).  A
 write batch (sync or async posting group) stages every pending block into
-one encode slab and encodes it in a few vectorized passes — one batched
-KV transform, one plane pack (pallas kernel on accelerator backends) and
-ONE ``codec.compress_batch`` over every (plane, block) stream — instead
-of O(blocks x planes) python-level calls (see ``Layout.encode_batch`` /
-``TierStore._post_writes``).  The per-block pipeline survives as
+one encode slab and encodes it in a few vectorized passes — per
+128K-element slab one KV transform and plane pack (one device program
+on a TPU) and ONE ``codec.compress_slab`` over every (plane, block)
+stream — instead of O(blocks x planes) python-level calls (see
+``Layout.encode_batch`` / ``TierStore._post_writes``).  The per-block pipeline survives as
 ``Layout.encode_batch_scalar`` (``TierStore(batched_encode=False)``),
 byte-identical by the encode differential tests.
 
@@ -148,6 +148,19 @@ from .kv_transform import (
 from .precision import EXP_BITS, PrecisionView, FULL, SCORE, reconstruct_u16
 
 INDEX_ENTRY_BYTES = 64  # paper §III-D: one compact entry per 4 KB block
+
+#: One encoded block: per-payload bytes, per-payload codec flags, KV meta.
+Encoded = Tuple[List[bytes], List[int], Optional[KVBlockMeta]]
+
+
+def _pad8_size(n: int) -> int:
+    """Elements a block of ``n`` encodes: planes pack whole bytes."""
+    return -(-n // 8) * 8
+
+
+def _pad8(t: np.ndarray) -> np.ndarray:
+    return np.pad(t, (0, _pad8_size(t.size) - t.size)) if t.size % 8 else t
+
 
 # Request payload kinds.
 TENSOR = "tensor"
@@ -686,33 +699,30 @@ class ResidencyEntry:
 class _EncodeSlab:
     """Per-posting-group staging area for deferred batched encoding.
 
-    Write staging appends each pending block here (with its key, receipt
-    and optional KV meta, kept in parallel lists); the group is then
-    packed+compressed in one ``Layout.encode_batch`` pass and committed in
-    staging order — the write-side mirror of the read side's shared decode
-    slab.  KV windows are staged UNtransformed (``kv_windows`` slot): the
-    exponent-delta transform is independent per window, so it too defers
-    and runs as a batched pass at encode time (``kv_forward_batch``).
+    Write staging appends each pending block here (with its key and
+    receipt, kept in parallel lists); the group is then packed+compressed
+    in one ``Layout.encode_batch`` pass and committed in staging order —
+    the write-side mirror of the read side's shared decode slab.  KV
+    windows are staged UNtransformed (``kv_windows`` slot, ``chunks``
+    None): the exponent-delta transform is independent per window, so the
+    layout runs it per encode slab, with the pack.
     """
 
-    __slots__ = ("keys", "recs", "chunks", "valids", "metas", "kv_windows")
+    __slots__ = ("keys", "recs", "chunks", "valids", "kv_windows")
 
     def __init__(self):
         self.keys: List[str] = []
         self.recs: List[Receipt] = []
         self.chunks: List[Optional[np.ndarray]] = []
         self.valids: List[int] = []
-        self.metas: List[Optional[KVBlockMeta]] = []
         self.kv_windows: List[Optional[np.ndarray]] = []
 
     def add(self, key: str, rec: Receipt, chunk: Optional[np.ndarray],
-            valid: int, meta: Optional[KVBlockMeta] = None,
-            kv_window: Optional[np.ndarray] = None):
+            valid: int, kv_window: Optional[np.ndarray] = None):
         self.keys.append(key)
         self.recs.append(rec)
         self.chunks.append(chunk)
         self.valids.append(valid)
-        self.metas.append(meta)
         self.kv_windows.append(kv_window)
 
     def clear(self):
@@ -720,7 +730,6 @@ class _EncodeSlab:
         self.recs.clear()
         self.chunks.clear()
         self.valids.clear()
-        self.metas.clear()
         self.kv_windows.clear()
 
 
@@ -773,8 +782,15 @@ class Layout:
     one ``compress_batch`` over every payload stream) — while
     :meth:`encode_batch_scalar` is the O(blocks x planes) per-block
     reference the device originally shipped with.  Both must produce
-    byte-identical payloads and flags; the encode differential tests hold
-    them to that.
+    byte-identical payloads, flags and KV metas; the encode differential
+    tests hold them to that.
+
+    Both take the group's blocks as ``chunks`` and, on a ``kv_transform``
+    layout, ``kv_windows``: entry ``i`` is either a ready block
+    (``chunks[i]``) or an untransformed ``(n, C)`` KV window
+    (``kv_windows[i]``, ``chunks[i]`` None), which the layout transforms
+    and encodes as one block.  Each returns one ``(payloads, flags,
+    kv_meta)`` per entry, ``kv_meta`` None for a ready block.
     """
 
     name = "layout"
@@ -782,13 +798,15 @@ class Layout:
     kv_transform = False
     uses_codec = False
 
-    def encode_batch(self, chunks: Sequence[np.ndarray],
-                     codec: str) -> List[Tuple[List[bytes], List[int]]]:
-        """Vectorized batch encode: one entry ``(payloads, flags)`` per chunk."""
+    def encode_batch(self, chunks: Sequence[Optional[np.ndarray]], codec: str,
+                     kv_windows: Optional[Sequence] = None) -> List[Encoded]:
+        """Vectorized batch encode."""
         raise NotImplementedError
 
-    def encode_batch_scalar(self, chunks: Sequence[np.ndarray],
-                            codec: str) -> List[Tuple[List[bytes], List[int]]]:
+    def encode_batch_scalar(self, chunks: Sequence[Optional[np.ndarray]],
+                            codec: str,
+                            kv_windows: Optional[Sequence] = None
+                            ) -> List[Encoded]:
         """Per-block reference encode (parity oracle + benchmark baseline)."""
         raise NotImplementedError
 
@@ -813,14 +831,14 @@ class WordLayout(Layout):
         self.uses_codec = compress
         self.name = "word-comp" if compress else "word"
 
-    def encode_batch(self, chunks, codec):
+    def encode_batch(self, chunks, codec, kv_windows=None):
         raws = [chunk.tobytes() for chunk in chunks]
         if self.compress:
             payloads, flags = codecs.compress_batch(raws, codec)
-            return [([pay], [fl]) for pay, fl in zip(payloads, flags)]
-        return [([raw], [codecs.RAW]) for raw in raws]
+            return [([pay], [fl], None) for pay, fl in zip(payloads, flags)]
+        return [([raw], [codecs.RAW], None) for raw in raws]
 
-    def encode_batch_scalar(self, chunks, codec):
+    def encode_batch_scalar(self, chunks, codec, kv_windows=None):
         out = []
         for chunk in chunks:
             raw = chunk.tobytes()
@@ -828,7 +846,7 @@ class WordLayout(Layout):
                 out.append(codecs.compress_block(raw, codec))
             else:
                 out.append((raw, codecs.RAW))
-        return [([pay], [fl]) for pay, fl in out]
+        return [([pay], [fl], None) for pay, fl in out]
 
     def fetched_payloads(self, block, view):
         return (0,)
@@ -873,24 +891,49 @@ class BitplaneLayout(Layout):
                 raise ValueError(f"block length {n} not a multiple of 8")
         return sizes
 
-    def encode_batch(self, chunks, codec):
+    @staticmethod
+    def _forward_on_host(chunks, windows):
+        """The KV transform's numpy twin: each window's transformed stream
+        (zero-padded to a byte multiple) in place of its chunk, and its
+        meta.  Same-shape windows go through one ``kv_forward_batch``."""
+        chunks, metas = list(chunks), [None] * len(chunks)
+        groups: Dict[tuple, List[int]] = {}
+        for i, w in enumerate(windows):
+            if w is not None:
+                groups.setdefault(w.shape, []).append(i)
+        for idxs in groups.values():
+            streams, ms = kv_forward_batch(np.stack([windows[i] for i in idxs]))
+            for i, row, meta in zip(idxs, streams, ms):
+                chunks[i] = _pad8(row)
+                metas[i] = meta
+        obs.count("tier.kv_host_windows", sum(map(len, groups.values())))
+        return chunks, metas
+
+    def encode_batch(self, chunks, codec, kv_windows=None):
         if not chunks:
             return []
-        sizes = self._check_sizes(chunks)
-        if len(chunks) > 1 and sum(sizes) > self.ENCODE_SLAB_ELEMS:
-            out, cur, cur_n = [], [], 0
-            for c in chunks:
-                if cur and cur_n + c.size > self.ENCODE_SLAB_ELEMS:
-                    out.extend(self._encode_slab(cur, codec))
-                    cur, cur_n = [], 0
-                cur.append(c)
-                cur_n += c.size
-            out.extend(self._encode_slab(cur, codec))
-            return out
-        return self._encode_slab(chunks, codec)
+        windows = list(kv_windows or [None] * len(chunks))
+        self._check_sizes([c for c in chunks if c is not None])
+        out, start, cur_n = [], 0, 0
+        for i, (c, w) in enumerate(zip(chunks, windows)):
+            n = c.size if w is None else _pad8_size(w.size)
+            if i > start and cur_n + n > self.ENCODE_SLAB_ELEMS:
+                out.extend(self._encode_slab(chunks[start:i], windows[start:i],
+                                             codec))
+                start, cur_n = i, 0
+            cur_n += n
+        out.extend(self._encode_slab(chunks[start:], windows[start:], codec))
+        return out
 
-    def _encode_slab(self, chunks, codec):
-        """One pack + ONE compress_slab for every (plane, block) stream.
+    def _encode_slab(self, chunks, windows, codec):
+        """KV transform + one pack + ONE compress_slab for every (plane,
+        block) stream.
+
+        A slab of same-shape KV windows alone is transformed and packed in
+        one device program (:func:`~repro.kernels.bitplane.kv_pack_planes_slab`)
+        where ``dispatch.choose("kv_pack")`` picks the device; any other
+        slab, and every slab off the TPU, transforms its windows on the
+        host and packs with ``pack_planes_slab``.  Both give the same bytes.
 
         Blocks are padded to a byte multiple, so their plane streams
         concatenate cleanly: packing the concatenation and slicing per
@@ -898,12 +941,33 @@ class BitplaneLayout(Layout):
         plane matrix is handed to the codec as a flat slab with (start,
         end) stream bounds — no per-stream bytes are materialized.
         """
-        from ..kernels.bitplane import pack_planes_slab
+        from ..kernels import dispatch
+        from ..kernels.bitplane import kv_pack_planes_slab, pack_planes_slab
 
-        sizes = [c.size for c in chunks]
-        with obs.span("tier.pack"):
-            planes = pack_planes_slab(np.concatenate(chunks)
-                                      if len(chunks) > 1 else chunks[0].ravel())
+        kv = [w for w in windows if w is not None]
+        path = dispatch.NUMPY
+        if (kv and len(kv) == len(windows)
+                and len({w.shape for w in kv}) == 1 and kv[0].size % 8 == 0):
+            path = dispatch.choose("kv_pack")
+        if path == dispatch.NUMPY:
+            metas = [None] * len(chunks)
+            if kv:
+                with obs.span("tier.kv_transform"):
+                    chunks, metas = self._forward_on_host(chunks, windows)
+            with obs.span("tier.pack"):
+                planes = pack_planes_slab(
+                    np.concatenate(chunks) if len(chunks) > 1
+                    else chunks[0].ravel())
+            sizes = [c.size for c in chunks]
+        else:
+            with obs.span("tier.pack"):
+                planes, beta = kv_pack_planes_slab(
+                    kv, interpret=path == dispatch.INTERPRET)
+            with obs.span("tier.kv_transform"):
+                n, C = kv[0].shape
+                metas = [KVBlockMeta(beta=b, n_tokens=n, n_channels=C)
+                         for b in beta]
+            sizes = [w.size for w in kv]
         offs = np.cumsum([0] + [n // 8 for n in sizes])
         nblk = len(chunks)
         n8 = planes.shape[1]
@@ -916,23 +980,31 @@ class BitplaneLayout(Layout):
         )
         return [
             ([payloads[p * nblk + i] for p in range(BF16_BITS)],
-             [flags[p * nblk + i] for p in range(BF16_BITS)])
+             [flags[p * nblk + i] for p in range(BF16_BITS)],
+             metas[i])
             for i in range(nblk)
         ]
 
-    def encode_batch_scalar(self, chunks, codec):
-        # The original write pipeline: per-block plane pack, per-plane
-        # compress_block — O(blocks x planes) python-level calls.
+    def encode_batch_scalar(self, chunks, codec, kv_windows=None):
+        # The original write pipeline: per-window KV transform, per-block
+        # plane pack, per-plane compress_block — O(blocks x planes)
+        # python-level calls.
         out = []
+        chunks, metas = list(chunks), [None] * len(chunks)
+        for i, w in enumerate(kv_windows or ()):
+            if w is not None:
+                stream, metas[i] = kv_forward(w)
+                chunks[i] = _pad8(stream)
+                obs.count("tier.kv_host_windows")
         self._check_sizes(chunks)
-        for chunk in chunks:
+        for chunk, meta in zip(chunks, metas):
             planes = pack_planes(chunk.ravel())
             payloads, flags = [], []
             for p in range(BF16_BITS):
                 pay, fl = codecs.compress_block(planes[p].tobytes(), codec)
                 payloads.append(pay)
                 flags.append(fl)
-            out.append((payloads, flags))
+            out.append((payloads, flags, meta))
         return out
 
     def fetched_payloads(self, block, view):
@@ -1432,7 +1504,7 @@ class TierStore:
         receipt-identity invariant cannot drift.
 
         Staging walks the requests in listed order, turning tensors into
-        fixed-size blocks and KV rows into transformed windows, but defers
+        fixed-size blocks and KV rows into windows, but defers the transform,
         pack + codec: every staged block lands in one slab that the layout
         encodes in a few vectorized passes (``encode_batch``), mirroring
         how queued reads share one decode slab.  Block commit order — and
@@ -1525,52 +1597,16 @@ class TierStore:
         if not slab.chunks:
             return
         with obs.span("tier.encode"):
-            with obs.span("tier.kv_transform"):
-                self._transform_kv_windows(slab)
             enc = (self.layout.encode_batch if self.batched_encode
                    else self.layout.encode_batch_scalar)
-            encoded = enc(slab.chunks, self.codec)
-            for (payloads, flags), key, rec, chunk, valid, meta in zip(
-                    encoded, slab.keys, slab.recs, slab.chunks, slab.valids,
-                    slab.metas):
+            encoded = enc(slab.chunks, self.codec, slab.kv_windows)
+            for (payloads, flags, meta), key, rec, chunk, valid in zip(
+                    encoded, slab.keys, slab.recs, slab.chunks, slab.valids):
+                padded = chunk.size if chunk is not None else _pad8_size(valid)
                 self._commit(rec, key,
-                             _Block(payloads, flags, valid, chunk.size,
+                             _Block(payloads, flags, valid, padded,
                                     kv_meta=meta))
             slab.clear()
-
-    def _transform_kv_windows(self, slab: "_EncodeSlab"):
-        """Resolve deferred KV windows into transformed chunks + metas.
-
-        Batched mode groups same-shape windows through one
-        ``kv_forward_batch`` (vectorized modal-exponent + zigzag); the
-        scalar reference transforms per window — identical outputs, the
-        parity tests compare them.
-        """
-        pend = [i for i, w in enumerate(slab.kv_windows) if w is not None]
-        if not pend:
-            return
-
-        def _pad(t: np.ndarray) -> np.ndarray:
-            return (np.pad(t, (0, 8 - t.size % 8)) if t.size % 8 else t)
-
-        if not self.batched_encode:
-            for i in pend:
-                transformed, meta = kv_forward(slab.kv_windows[i])
-                slab.chunks[i] = _pad(transformed)
-                slab.metas[i] = meta
-                slab.kv_windows[i] = None
-            return
-        groups: Dict[tuple, List[int]] = {}
-        for i in pend:
-            groups.setdefault(slab.kv_windows[i].shape, []).append(i)
-        for shape, idxs in groups.items():
-            streams, metas = kv_forward_batch(
-                np.stack([slab.kv_windows[i] for i in idxs])
-            )
-            for i, row, meta in zip(idxs, streams, metas):
-                slab.chunks[i] = _pad(row)
-                slab.metas[i] = meta
-                slab.kv_windows[i] = None
 
     def _commit(self, rec: Receipt, key: str, block: _Block):
         self._tensors.setdefault(key, []).append(block)

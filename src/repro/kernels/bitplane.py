@@ -31,6 +31,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..core.bitplane import BF16_BITS
+from ..core.kv_transform import kv_beta_jnp, kv_forward_jnp
 from . import dispatch
 
 DEFAULT_BLOCK_R = 64
@@ -126,6 +127,51 @@ def pack_planes_slab(flat_u16, force: str | None = None):
     )
     planes = np.asarray(planes).reshape(BF16_BITS, npad // 8)
     return np.ascontiguousarray(planes[:, : n // 8])
+
+
+def kv_pack_planes_slab(windows, interpret: bool = False):
+    """Transform and pack one encode slab of KV windows on the device.
+
+    ``windows``: ``B`` same-shape ``(n, C)`` uint16 token-major windows,
+    untransformed, with ``n * C`` a multiple of 8.  Returns ``(planes,
+    beta)``: the ``(16, B * n * C // 8)`` uint8 planes of the windows'
+    transformed channel-major streams in window order, and the ``(B, C)``
+    uint8 modal exponents.  Its numpy twin is
+    :func:`~repro.core.kv_transform.kv_forward_batch` followed by
+    :func:`pack_planes_slab`, which gives the same bytes; the tier picks
+    between them per slab (``dispatch.choose("kv_pack")``).
+
+    ``B`` is zero-padded to :func:`dispatch.bucket`, so a run compiles a
+    handful of shapes; the padded windows' planes and betas are sliced
+    off.  ``interpret`` runs the pack kernel in pallas interpret mode.
+    """
+    B = len(windows)
+    n, C = windows[0].shape
+    if (n * C) % 8:
+        raise ValueError(f"window of {n * C} elements not a multiple of 8")
+    x = np.zeros((dispatch.bucket(B), n, C), dtype=np.uint16)
+    np.stack(windows, out=x[:B])
+    planes, beta = jax.device_get(_kv_pack_program(jnp.asarray(x),
+                                                   interpret=interpret))
+    planes = planes.reshape(BF16_BITS, -1)
+    return np.ascontiguousarray(planes[:, : B * n * C // 8]), beta[:B]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_pack_program(windows, interpret: bool):
+    """(B, n, C) u16 windows → ((16, R, C // 8) u8 planes of the padded
+    stream, (B, C) u8 beta): modal exponents, the zigzag delta in
+    channel-major order and the plane pack, in one program.  The planes
+    leave as the kernel writes them; a reshape here would be a relayout
+    on the device, and the kernel would write to VMEM for it."""
+    beta = kv_beta_jnp(windows)
+    stream = jax.vmap(kv_forward_jnp)(windows, beta).reshape(-1)
+    npad = max(_PACK_MIN_ELEMS, dispatch.bucket(stream.size))
+    rows = npad // _PACK_C
+    planes = pack_planes_pallas(
+        jnp.pad(stream, (0, npad - stream.size)).reshape(rows, _PACK_C),
+        block_r=min(rows, DEFAULT_BLOCK_R), interpret=interpret)
+    return planes, beta
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))

@@ -1,9 +1,10 @@
 """The one backend dispatch point for the tier's device kernels.
 
-``pack_planes_slab`` (bit-plane pack), ``match_events_slab`` (LZ4 match
-scan) and ``page_scores`` (PNM page scoring) each have a device path — a
-pallas kernel under jit — and a numpy twin.  :func:`choose` picks one per
-call:
+``pack_planes_slab`` (bit-plane pack), ``kv_pack`` (the tier's KV
+transform with the pack, ``kv_pack_planes_slab``), ``match_events_slab``
+(LZ4 match scan) and ``page_scores`` (PNM page scoring) each have a
+device path — a pallas kernel under jit — and a numpy twin.
+:func:`choose` picks one per call:
 
 * on a TPU backend the device path runs, compiled for the chip;
 * on any other backend the numpy twin runs;

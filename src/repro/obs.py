@@ -44,8 +44,9 @@ SPAN_NAMES = (
 )
 #: The fixed counter names; ``kernel.<kernel>.<path>`` counts each
 #: backend decision of ``repro.kernels.dispatch.choose`` besides.
+#: ``tier.kv_host_windows`` counts KV windows transformed on the host.
 COUNTER_NAMES = ("commit.d2h_bytes", "commit.copy_bytes", "commit.h2d_bytes",
-                 "sched.tokens")
+                 "sched.tokens", "tier.kv_host_windows")
 
 _DECLARED = frozenset(SPAN_NAMES)
 

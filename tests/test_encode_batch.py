@@ -13,7 +13,9 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import codec, synth
+from repro.core.bitplane import pack_planes
 from repro.core.kv_transform import kv_forward, kv_forward_batch
 from repro.core.tier import (
     KV,
@@ -23,6 +25,8 @@ from repro.core.tier import (
     TierStore,
     WriteReq,
 )
+from repro.kernels import dispatch
+from repro.kernels.bitplane import kv_pack_planes_slab
 
 RECEIPT_FIELDS = (
     "dram_bytes_read", "dram_bytes_written", "dram_bytes_stored",
@@ -197,6 +201,133 @@ def test_pack_planes_slab_pallas_matches_numpy():
         # the pallas kernel path (interpret mode on CPU) packs identically
         np.testing.assert_array_equal(
             pack_planes_slab(flat, force="pallas"), pack_planes(flat))
+
+
+def _bf16(rng, exp, shape):
+    """uint16 BF16 patterns with the given exponent field and random sign
+    and mantissa."""
+    sign = rng.integers(0, 2, shape).astype(np.uint16) << 15
+    man = rng.integers(0, 128, shape).astype(np.uint16)
+    return sign | (np.asarray(exp, dtype=np.uint16) << 7) | man
+
+
+def _tied_windows(rng, B, n, C):
+    """Every channel holds two exponents, n // 2 tokens each, shuffled:
+    the mode is a tie in every group, broken to the smaller exponent."""
+    out = []
+    for _ in range(B):
+        pair = rng.integers(100, 140, (2, C))
+        pair[1] = np.where(pair[1] == pair[0], pair[0] + 1, pair[1])
+        exp = np.repeat(pair, n // 2, axis=0)            # (n, C)
+        exp = np.take_along_axis(exp, rng.permuted(
+            np.tile(np.arange(n)[:, None], (1, C)), axis=0), axis=0)
+        out.append(_bf16(rng, exp, (n, C)))
+    return out
+
+
+def _special_windows(rng, B, n, C):
+    """±Inf (0x7F80 / 0xFF80) and NaN patterns among normal values, and
+    one window of NaNs alone: exponent 0xFF as a minority and as mode."""
+    out = []
+    specials = np.array([0x7F80, 0xFF80, 0x7FC0, 0xFFC1, 0x7F81],
+                        dtype=np.uint16)
+    for b in range(B - 1):
+        w = _bf16(rng, rng.integers(118, 130, (n, C)), (n, C))
+        hit = rng.random((n, C)) < (0.1 if b % 2 else 0.7)
+        w[hit] = rng.choice(specials, hit.sum())
+        out.append(w)
+    out.append(np.full((n, C), 0x7FC0, dtype=np.uint16))
+    return out
+
+
+_KV_SLABS = {
+    # B not a power of two: the padded windows are sliced off
+    "n16_decode_boundary": lambda rng: list(
+        synth.kv_cache(48 * 16, 128, seed=11).reshape(48, 16, 128)),
+    "n64": lambda rng: [synth.kv_cache(64, 32, seed=12 + i)
+                        for i in range(5)],
+    "ties": lambda rng: _tied_windows(rng, 3, 16, 128),
+    "equal_and_zero": lambda rng: [
+        np.full((16, 128), 0x3F80, dtype=np.uint16),
+        np.zeros((16, 128), dtype=np.uint16),
+        np.full((16, 128), 0xC2F7, dtype=np.uint16)],
+    "inf_nan": lambda rng: _special_windows(rng, 7, 64, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KV_SLABS))
+def test_kv_pack_planes_slab_matches_numpy_twin(case):
+    """The device program (pallas interpret mode here) gives the planes
+    and betas of ``kv_forward_batch`` + ``pack_planes``, byte for byte."""
+    windows = _KV_SLABS[case](np.random.default_rng(7))
+    planes, beta = kv_pack_planes_slab(windows, interpret=True)
+    streams, metas = kv_forward_batch(np.stack(windows))
+    assert planes.dtype == beta.dtype == np.uint8
+    np.testing.assert_array_equal(planes, pack_planes(streams.ravel()))
+    np.testing.assert_array_equal(beta, np.stack([m.beta for m in metas]))
+
+
+def _spill_group(mixed: bool):
+    """One admission's spill: 384 page windows of (16, 128), one request
+    each; ``mixed`` puts a TENSOR write first, so the first encode slab
+    holds a tensor block beside 63 windows."""
+    pages = synth.kv_cache(384 * 16, 128, seed=21).reshape(384, 16, 128)
+    reqs = [WriteReq(f"L{i // 16}.p{i % 16}", p, kind=KV, flush=True)
+            for i, p in enumerate(pages)]
+    if mixed:
+        reqs.insert(0, WriteReq("w", synth.weights(2048, seed=22)))
+    return reqs
+
+
+def _pin_kv_pack(monkeypatch, force):
+    """Pin ``dispatch.choose``'s path for ``kv_pack`` alone."""
+    choose = dispatch.choose
+    monkeypatch.setattr(dispatch, "choose", lambda kernel, f=None: choose(
+        kernel, force if kernel == "kv_pack" else f))
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["spill", "mixed"])
+def test_kv_pack_device_path_stores_what_the_twin_stores(mixed, monkeypatch):
+    """A spill posting group through the device transform+pack and
+    through the numpy twin: identical payloads, flags, metas, receipts
+    and read-back."""
+    reqs = _spill_group(mixed)
+    twin = TierStore(layout="bitplane-kv", kv_window=16)
+    dev = TierStore(layout="bitplane-kv", kv_window=16)
+    t_recs = twin.submit(reqs)
+    _pin_kv_pack(monkeypatch, "pallas")
+    d_recs = dev.submit(reqs)
+    assert _storage_state(twin) == _storage_state(dev)
+    for t, d in zip(t_recs, d_recs):
+        for f in RECEIPT_FIELDS:
+            assert getattr(t, f) == getattr(d, f), f
+    keys = [(r.key, r.kind) for r in reqs[:: 37]] + [(reqs[-1].key, KV)]
+    reads = [ReadReq(k, kind=kind) for k, kind in keys]
+    for a, b, (k, _) in zip(twin.submit(reads), dev.submit(reads), keys):
+        np.testing.assert_array_equal(a.data, b.data)
+        np.testing.assert_array_equal(
+            b.data.ravel(), next(r.data for r in reqs if r.key == k).ravel())
+
+
+@pytest.mark.parametrize("force, batched, host, decisions", [
+    ("pallas", True, 0, {"kernel.kv_pack.interpret": 2}),
+    (None, True, 80, {"kernel.kv_pack.numpy": 2}),
+    (None, False, 80, {}),
+], ids=["device", "cpu", "scalar"])
+def test_kv_host_windows_counter(force, batched, host, decisions,
+                                 monkeypatch):
+    """``tier.kv_host_windows`` counts the windows transformed on the
+    host; a slab of windows alone is one ``kv_pack`` decision."""
+    pages = synth.kv_cache(80 * 16, 128, seed=23).reshape(80, 16, 128)
+    _pin_kv_pack(monkeypatch, force)
+    dev = TierStore(layout="bitplane-kv", kv_window=16,
+                    batched_encode=batched)
+    obs.reset()
+    dev.submit([WriteReq(f"p{i}", p, kind=KV) for i, p in enumerate(pages)])
+    counters = obs.snapshot()["counters"]
+    assert counters.get("tier.kv_host_windows", 0) == host
+    assert {k: n for k, n in counters.items()
+            if k.startswith("kernel.kv_pack.")} == decisions
 
 
 def test_batched_encode_faster_than_scalar():

@@ -84,6 +84,22 @@ def test_pack_planes_compiles_for_v5e(compile_for, elems):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("windows", [64, 32])
+def test_kv_pack_program_compiles_for_v5e(compile_for, windows):
+    """The KV transform and pack program over a slab of qwen2-0.5b page
+    windows (16 tokens x 128 channels): 64 is an admission's slab and a
+    batch-1 decode boundary's 48 windows padded; 32 the rest of a
+    batch-2 decode boundary's 96.  The pack kernel writes its planes to
+    HBM, as it does alone (no ``S(1)`` on its result)."""
+    compiled = compile_for(kb._kv_pack_program,
+                           ((windows, 16, KV_CHANNELS), jnp.uint16),
+                           interpret=False)
+    _assert_kernel(compiled)
+    pack, = [line for line in compiled.as_text().splitlines()
+             if "%pack_planes_pallas" in line and "custom-call(" in line]
+    assert "S(1)" not in pack.split("custom-call(")[0]
+
+
 def test_lz4_match_pipeline_compiles_for_v5e(compile_for):
     """The whole jitted match pipeline over one packed 128K-element
     slab: 16 planes x 64 blocks of ``BLOCK_ELEMS // 8``-byte streams."""
