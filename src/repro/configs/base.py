@@ -30,6 +30,7 @@ class ArchConfig:
     norm: str = "rmsnorm"        # rmsnorm | layernorm
     tie_embeddings: bool = False
     rope_theta: float = 1e4
+    norm_eps: float = 1e-6       # RMSNorm epsilon (published rms_norm_eps)
 
     # MoE
     n_experts: int = 0

@@ -90,7 +90,7 @@ def make_pp_prefill_step(cfg: ArchConfig, mesh, n_micro: int = 8):
                 h = _stage_forward(cfg, p_layers, h_in, positions)
                 h = jnp.where(active, h, cur)
                 # last stage emits logits for microbatch t - last
-                hn = L.norm(h, fnorm, cfg.norm)
+                hn = L.norm(h, fnorm, cfg.norm, cfg.norm_eps)
                 logits = (hn @ head_w).astype(DTYPE)
                 m_out = jnp.clip(t - last, 0, n_micro - 1)
                 emit = active & (stage == last)

@@ -35,10 +35,11 @@ def layernorm(x, scale, bias, eps=1e-5):
     return (out * (1.0 + scale) + bias).astype(x.dtype)
 
 
-def norm(x, p, kind: str):
+def norm(x, p, kind: str, eps: float = 1e-6):
+    """``kind`` norm of ``x``; ``eps`` is the RMSNorm epsilon."""
     if kind == "layernorm":
         return layernorm(x, p["scale"], p["bias"])
-    return rmsnorm(x, p["scale"])
+    return rmsnorm(x, p["scale"], eps)
 
 
 # ---------------------------------------------------------------------------
@@ -68,6 +69,13 @@ def _masked_softmax(scores, mask):
     return jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
 
 
+def query_chunk(S: int) -> int:
+    """Query positions per chunk that attention over ``S`` queries scans
+    (0: one block).  Prompts past 1024 tokens scan chunks of 256, which
+    bounds the score matrix to 256 x Sk."""
+    return 256 if S > 1024 and S % 256 == 0 else 0
+
+
 def attention(
     q, k, v,
     *,
@@ -83,7 +91,8 @@ def attention(
     q: (B, Sq, H, hd);  k, v: (B, Sk, KV, hd) with H % KV == 0.
     ``window`` > 0 applies sliding-window attention (zamba2 long-context).
     ``kv_valid_len``: (B,) or scalar — mask out cache slots beyond it.
-    ``q_chunk`` > 0 scans query chunks to bound score memory.
+    ``q_chunk`` > 0 scans query chunks of that size (a divisor of Sq)
+    to bound score memory; callers take it from :func:`query_chunk`.
     """
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -115,7 +124,7 @@ def attention(
         return o.astype(q.dtype)
 
     dv = v.shape[-1]  # may differ from hd (MLA)
-    if q_chunk and Sq > q_chunk and Sq % q_chunk == 0:
+    if q_chunk:
         nc = Sq // q_chunk
         qc = qg.reshape(B, nc, q_chunk, KV, rep, hd).transpose(1, 0, 2, 3, 4, 5)
         pc = jnp.broadcast_to(q_positions, (B, Sq))
@@ -138,7 +147,7 @@ def gqa_block(x, p, cfg, *, positions, cache=None, cache_pos=None,
     """
     B, S, d = x.shape
     H, KVh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    h = norm(x, p["norm"], cfg.norm)
+    h = norm(x, p["norm"], cfg.norm, cfg.norm_eps)
     q = jnp.einsum("bsd,dh->bsh", h, p["wq"])
     k = jnp.einsum("bsd,dh->bsh", h, p["wk"])
     v = jnp.einsum("bsd,dh->bsh", h, p["wv"])
@@ -156,7 +165,7 @@ def gqa_block(x, p, cfg, *, positions, cache=None, cache_pos=None,
         k = logical(k, "batch", "seq", "kv_heads", None)
         out = attention(
             q, k, v, causal=cfg.causal, window=window,
-            q_positions=positions, q_chunk=256 if S > 1024 else 0,
+            q_positions=positions, q_chunk=query_chunk(S),
         )
         new_cache = None
     else:
@@ -187,7 +196,7 @@ def gqa_block(x, p, cfg, *, positions, cache=None, cache_pos=None,
             window=window,
             q_positions=positions, k_positions=kpos,
             kv_valid_len=valid,
-            q_chunk=256 if S > 1024 else 0,
+            q_chunk=query_chunk(S),
         )
         new_cache = {"k": kc, "v": vc}
     out = out.reshape(B, S, H * hd) @ p["wo"]
@@ -204,7 +213,7 @@ def mla_block(x, p, cfg, *, positions, cache=None, cache_pos=None):
     B, S, d = x.shape
     H, hd, r = cfg.n_heads, cfg.hd, cfg.kv_lora_rank
     rd, vh = cfg.qk_rope_dim, cfg.v_head_dim or cfg.hd
-    h = norm(x, p["norm"], cfg.norm)
+    h = norm(x, p["norm"], cfg.norm, cfg.norm_eps)
 
     q = (h @ p["wq"]).reshape(B, S, H, hd + rd)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
@@ -243,7 +252,7 @@ def mla_block(x, p, cfg, *, positions, cache=None, cache_pos=None):
     out = attention(
         qfull, k, v, causal=True,
         q_positions=positions, k_positions=k_positions,
-        kv_valid_len=kv_valid, q_chunk=256 if S > 1024 else 0,
+        kv_valid_len=kv_valid, q_chunk=query_chunk(S),
     )
     out = out.reshape(B, S, H * vh) @ p["wo"]
     return out, new_cache
@@ -267,7 +276,7 @@ def mlp_apply(h, p, kind: str):
 
 
 def mlp_block(x, p, cfg):
-    h = norm(x, p["norm"], cfg.norm)
+    h = norm(x, p["norm"], cfg.norm, cfg.norm_eps)
     return mlp_apply(h, p, cfg.mlp)
 
 
@@ -365,7 +374,7 @@ def moe_block_ep(x, p, cfg, mesh, token_axes):
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
     M = mesh.shape["model"]
-    h = norm(x, p["norm"], cfg.norm)
+    h = norm(x, p["norm"], cfg.norm, cfg.norm_eps)
     flat = h.reshape(B * S, d)
     T = B * S
     nshards = 1
@@ -464,7 +473,7 @@ def moe_block(x, p, cfg):
         if (B * S) % nshards == 0:
             return moe_block_ep(x, p, cfg, r.mesh, token_axes)
 
-    h = norm(x, p["norm"], cfg.norm)
+    h = norm(x, p["norm"], cfg.norm, cfg.norm_eps)
     flat = h.reshape(B * S, d)
     T = B * S
 

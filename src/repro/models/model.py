@@ -319,7 +319,7 @@ def _embed_in(cfg, params, batch):
 
 
 def _logits_out(cfg, params, x):
-    x = L.norm(x, params["final_norm"], cfg.norm)
+    x = L.norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = x @ head
     return logical(logits, "batch", "seq", "vocab")
@@ -423,7 +423,7 @@ def _forward_mamba(cfg, params, x, cache):
             p_l, st = xs
         else:
             p_l, st = xs, None
-        hin = L.norm(h, p_l["norm"], cfg.norm)
+        hin = L.norm(h, p_l["norm"], cfg.norm, cfg.norm_eps)
         y, new_st = L.mamba1_mix(hin, p_l, cfg, state=st)
         return h + y, new_st
 
@@ -461,7 +461,7 @@ def _forward_hybrid(cfg, params, x, positions, cache, cache_pos):
             (h, i) = carry
             p_l, st = xs, None
 
-        hin = L.norm(h, p_l["norm"], cfg.norm)
+        hin = L.norm(h, p_l["norm"], cfg.norm, cfg.norm_eps)
         y, new_st = L.mamba2_mix(hin, p_l, cfg, state=st)
         h = h + y
 

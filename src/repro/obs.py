@@ -45,8 +45,11 @@ SPAN_NAMES = (
 #: The fixed counter names; ``kernel.<kernel>.<path>`` counts each
 #: backend decision of ``repro.kernels.dispatch.choose`` besides.
 #: ``tier.kv_host_windows`` counts KV windows transformed on the host.
+#: ``model.prefill_chunked`` counts the prefills whose attention scans
+#: query chunks (``models.layers.query_chunk``).
 COUNTER_NAMES = ("commit.d2h_bytes", "commit.copy_bytes", "commit.h2d_bytes",
-                 "sched.tokens", "tier.kv_host_windows")
+                 "sched.tokens", "tier.kv_host_windows",
+                 "model.prefill_chunked")
 
 _DECLARED = frozenset(SPAN_NAMES)
 

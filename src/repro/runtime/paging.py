@@ -307,7 +307,7 @@ class KVPagePool:
         """Commit one full page (token-major (n, C) uint16)."""
         self.append_pages([(layer, kind, start, tokens_u16, importance)])
 
-    def append_pages(self, pages: Sequence[tuple]):
+    def append_pages(self, pages: Sequence[tuple], copy: bool = True):
         """Commit a batch of pages — ``(layer, kind, start, tokens_u16,
         importance)`` each, with an optional sixth ``share_hash`` element
         (see :func:`prefix_chain_hashes`) — with ONE eviction pass at the
@@ -323,6 +323,10 @@ class KVPagePool:
         behave exactly as for private pages (so solo-run differentials
         hold), but the spill write is elided when an identical page is
         already stored — the pool acquires a ledger reference instead.
+
+        ``copy=False`` keeps the callers' arrays as the resident pages
+        instead of copies: for a caller that hands them over and never
+        writes to them again.
         """
         with obs.span("commit.append"):
             self._commit_clock += 1
@@ -341,7 +345,7 @@ class KVPagePool:
                 # Always admit to HBM first, then evict the least-important
                 # pages (possibly this one) — importance, not arrival order,
                 # decides residency (paper §II-C: importance is long-tailed).
-                page.resident = tokens_u16.copy()
+                page.resident = tokens_u16.copy() if copy else tokens_u16
                 self._hbm_used += tokens_u16.size * 2
                 self._pages.append(page)
             self._rebalance()

@@ -64,6 +64,7 @@ from ..configs.base import ArchConfig
 from ..core.system_model import SystemSpec
 from ..core.tier import Ticket, TierStore, make_device
 from ..models import decode_step, forward, init_cache
+from ..models.layers import query_chunk
 from .paging import (
     KVPagePool, PagePolicy, PAPER_POLICY, PrefixShareIndex, _Page,
     prefix_chain_hashes, shared_page_key,
@@ -73,6 +74,12 @@ from .paging import (
 # every engine — N streams of the same model trace and compile once, not
 # N times.
 _jit_step = jax.jit(decode_step, static_argnums=0)
+
+#: Most tokens of completed KV windows that one device slice brings to the
+#: host.  A long prompt's commit pulls in slices of this size: on a v5e
+#: host one 2048-token slice of Mistral-7B's K and V (64 MB) took 84-100
+#: ms to reach numpy, a 1024-token one 12-21 ms.
+_PULL_TOKENS = 1024
 
 
 def _sample_next(logits: np.ndarray, rng: np.random.Generator,
@@ -182,46 +189,57 @@ class ServeEngine:
                 return  # SSM/hybrid: constant-size state, nothing paged
             pt = self.page_tokens
             starts = [s for s in range(lo - lo % pt, hi, pt) if s + pt <= hi]
-            bufs = {}
-            if starts:
-                with obs.span("commit.pull"):
-                    for kind in kv_keys:
-                        # (L, B, S, ...) bf16
-                        bufs[kind] = np.asarray(layers[kind])
-                        obs.count("commit.d2h_bytes", bufs[kind].nbytes)
             # Gather every completed window across layers and kinds into one
             # batched admission: the spill this triggers goes to the device as
             # one write batch → one vectorized encode slab, instead of a
             # per-page pack+codec pipeline.
             batch_pages = []
-            for start in starts:
-                for kind in kv_keys:
-                    buf = bufs[kind]
-                    n_layers = buf.shape[0]
-                    # Windows fully inside the prompt carry their prefix
-                    # chain hash: identical prompt prefixes produce identical
-                    # KV there (causal attention), so these pages are the
-                    # shareable ones.  Windows touching generated tokens stay
-                    # private — that is the copy-on-write divergence point.
-                    share = None
-                    if (self._share_hashes
-                            and start + self.page_tokens <= self._prompt_len):
-                        share = self._share_hashes[start // self.page_tokens]
-                    for layer in range(n_layers):
-                        page = buf[layer, :, start : start + self.page_tokens]
-                        tok = page.reshape(self.page_tokens * self.batch, -1)
-                        u16 = np.ascontiguousarray(tok).view(np.uint16)
-                        # recency as default importance; importance="attention"
-                        # replaces it below with accumulated attention mass and
-                        # keeps re-ranking live pages via
-                        # pool.update_importance every boundary
-                        batch_pages.append(
-                            (layer, kind, start, u16, float(start), share)
-                        )
+            per_pull = max(_PULL_TOKENS // pt, 1)
+            for g in range(0, len(starts), per_pull):
+                group = starts[g : g + per_pull]
+                # only the completed windows come to the host, not the
+                # whole cache: one device slice per kind, whose size (and
+                # so its compiled program) depends on the window count alone
+                lo_tok, n_tok = group[0], group[-1] + pt - group[0]
+                bufs = {}
+                with obs.span("commit.pull"):
+                    for kind in kv_keys:
+                        # (L, B, n_tok, ...) bf16
+                        bufs[kind] = np.asarray(jax.lax.dynamic_slice_in_dim(
+                            layers[kind], np.int32(lo_tok), n_tok, axis=2))
+                        obs.count("commit.d2h_bytes", bufs[kind].nbytes)
+                for start in group:
+                    for kind in kv_keys:
+                        buf = bufs[kind]
+                        n_layers = buf.shape[0]
+                        # Windows fully inside the prompt carry their prefix
+                        # chain hash: identical prompt prefixes produce
+                        # identical KV there (causal attention), so these
+                        # pages are the shareable ones.  Windows touching
+                        # generated tokens stay private — that is the
+                        # copy-on-write divergence point.
+                        share = None
+                        if (self._share_hashes
+                                and start + pt <= self._prompt_len):
+                            share = self._share_hashes[start // pt]
+                        for layer in range(n_layers):
+                            page = buf[layer, :, start - lo_tok : start - lo_tok + pt]
+                            tok = page.reshape(pt * self.batch, -1)
+                            u16 = np.ascontiguousarray(tok).view(np.uint16)
+                            # recency as default importance;
+                            # importance="attention" replaces it below with
+                            # accumulated attention mass and keeps re-ranking
+                            # live pages via pool.update_importance every
+                            # boundary
+                            batch_pages.append(
+                                (layer, kind, start, u16, float(start), share)
+                            )
             if batch_pages:
                 if self.importance == "attention":
                     batch_pages = self._apply_attention_importance(batch_pages)
-                self.pool.append_pages(batch_pages)
+                # the pulled buffers belong to this commit alone, so the
+                # pool keeps its pages as views of them
+                self.pool.append_pages(batch_pages, copy=False)
             self._issue_readback()
 
     def _issue_readback(self):
@@ -414,6 +432,7 @@ class ServeEngine:
         }
         with obs.span("model.prefill", self.tag):
             logits, self.cache = self._prefill(self.params, batch, self.cache)
+        obs.count("model.prefill_chunked", int(query_chunk(S) > 0))
         old = self.pos
         self.pos += S
         if old == 0 and self.pool.prefix_index is not None:

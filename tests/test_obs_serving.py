@@ -73,7 +73,8 @@ def served(smoke_model):
     max_seq = PROMPT + max(NEW) + PAGE
     buf_bytes = cfg.n_layers * max_seq * cfg.n_kv_heads * cfg.head_dim * 2
     return dict(rep=rep, snap=snap, spans=spans, commits=commits,
-                applied=applied, buf_bytes=buf_bytes)
+                applied=applied, buf_bytes=buf_bytes,
+                window_bytes=buf_bytes // max_seq * PAGE)
 
 
 def test_every_span_of_the_served_path_records(served):
@@ -101,13 +102,14 @@ def test_commit_total_matches_the_outside_timer(served):
 
 def test_byte_counters_by_hand(served):
     b = served["buf_bytes"]
-    pulls = sum(hi // PAGE > lo // PAGE for lo, hi in served["commits"])
+    # a commit pulls the windows it completes, not the whole cache
+    windows = sum(hi // PAGE - lo // PAGE for lo, hi in served["commits"])
     applies = [a for a in served["applied"] if a[0]]
     pages = sum(n for n, _ in applies)
     kinds = sum(len(k) for _, k in applies)
     c = served["snap"]["counters"]
-    assert pulls > 0 and pages > 0
-    assert c["commit.d2h_bytes"] == (2 * pulls + kinds) * b
+    assert windows > 0 and pages > 0
+    assert c["commit.d2h_bytes"] == 2 * windows * served["window_bytes"] + kinds * b
     assert c["commit.copy_bytes"] == pages * b
     assert c["commit.h2d_bytes"] == kinds * b
     assert c["sched.tokens"] == sum(NEW)

@@ -4,10 +4,12 @@ sync and async I/O, single- and multi-stream."""
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.precision import FULL
 from repro.runtime import (
     MultiStreamEngine, PAPER_POLICY, KVPagePool, ServeEngine,
 )
+from repro.runtime import serving
 from repro.runtime.paging import LOSSLESS_POLICY, PagePolicy
 
 pytestmark = pytest.mark.slow   # model-forward module
@@ -85,6 +87,46 @@ def test_page_pool_importance_eviction():
     assert len(spilled) == 4 and max(spilled) < min(resident)
     out = pool.read_layer(0, "k")
     assert out.shape == (48, 64)
+
+
+@pytest.mark.parametrize("copy", [True, False])
+def test_page_pool_keeps_handed_over_pages(copy):
+    """``copy=False`` keeps the caller's array as the resident page."""
+    pool = KVPagePool("trace", page_tokens=8, hbm_budget_bytes=1 << 20)
+    u16 = np.arange(8 * 64, dtype=np.uint16).reshape(8, 64)
+    pool.append_pages([(0, "k", 0, u16, 0.0)], copy=copy)
+    page = pool._pages[0].resident
+    assert (page is u16) is not copy
+    np.testing.assert_array_equal(page, u16)
+
+
+def test_commit_pulls_in_bounded_slices(engine_pair, monkeypatch):
+    """A commit over more windows than one pull takes brings them in
+    several device slices; the bytes pulled, the pages and the tokens
+    are those of one slice."""
+    cfg, params = engine_pair
+    runs = []
+    for limit in (serving._PULL_TOKENS, 16):
+        monkeypatch.setattr(serving, "_PULL_TOKENS", limit)
+        obs.reset()
+        obs.enable()
+        try:
+            eng, toks = _run(cfg, params, "trace", LOSSLESS_POLICY)
+        finally:
+            obs.disable()
+        runs.append((eng, toks, obs.snapshot()))
+    (one, t_one, s_one), (many, t_many, s_many) = runs
+    np.testing.assert_array_equal(t_one, t_many)
+    # the 48-token prompt completes 3 windows: 1 pull, then 3
+    assert s_one["spans"]["commit.pull"]["count"] == 1
+    assert s_many["spans"]["commit.pull"]["count"] == 3
+    assert (s_one["counters"]["commit.d2h_bytes"]
+            == s_many["counters"]["commit.d2h_bytes"])
+    assert many.stats().spilled_pages == one.stats().spilled_pages > 0
+    for layer in range(cfg.n_layers):
+        for kind in ("k", "v"):
+            np.testing.assert_array_equal(one.kv_through_tier(layer, kind),
+                                          many.kv_through_tier(layer, kind))
 
 
 def test_policy_rank_views():
