@@ -143,7 +143,8 @@ def gqa_block(x, p, cfg, *, positions, cache=None, cache_pos=None,
 
     ``cache``: optional dict {k: (B, Smax, KV, hd), v: ...} for decoding —
     the new token's K/V is written at ``cache_pos`` and attention runs over
-    the cache.  Returns (out, new_cache).
+    the cache.  A ``(B,)`` ``cache_pos`` writes and masks each row at its
+    own position.  Returns (out, new_cache).
     """
     B, S, d = x.shape
     H, KVh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -170,12 +171,19 @@ def gqa_block(x, p, cfg, *, positions, cache=None, cache_pos=None,
         new_cache = None
     else:
         cdt = cache["k"].dtype           # may be fp8 (elastic KV storage)
-        kc = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cdt), cache_pos, 1
-        )
-        vc = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cdt), cache_pos, 1
-        )
+        if jnp.ndim(cache_pos):
+            # one row, one position: a per-row scatter
+            put = jax.vmap(
+                lambda c, u, at: jax.lax.dynamic_update_slice_in_dim(c, u, at, 0))
+            kc = put(cache["k"], k.astype(cdt), cache_pos)
+            vc = put(cache["v"], v.astype(cdt), cache_pos)
+        else:
+            kc = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cdt), cache_pos, 1
+            )
+            vc = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cdt), cache_pos, 1
+            )
         kc = logical(kc, "batch", "kv_seq", "kv_heads", None)
         vc = logical(vc, "batch", "kv_seq", "kv_heads", None)
         if "kpos" in cache:

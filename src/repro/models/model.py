@@ -348,7 +348,9 @@ def forward(cfg: ArchConfig, params, batch, cache=None):
     """Returns (logits, new_cache, aux_loss).
 
     train/prefill: ``cache is None``; decode: ``cache`` is the stacked
-    cache pytree and ``batch['cache_pos']`` the write position.
+    cache pytree and ``batch['cache_pos']`` the write position, a scalar
+    for the whole batch or, for a plain GQA ``k``/``v`` cache, a ``(B,)``
+    vector that gives each row its own.
     """
     x = _embed_in(cfg, params, batch)
     B, S = x.shape[:2]
@@ -356,8 +358,9 @@ def forward(cfg: ArchConfig, params, batch, cache=None):
     cache_pos = batch.get("cache_pos") if decode else None
     if decode:
         # works for both decode (S=1) and prefill-into-cache (S=prompt)
+        start = cache_pos[:, None] if jnp.ndim(cache_pos) else cache_pos
         positions = jnp.broadcast_to(
-            cache_pos + jnp.arange(S, dtype=jnp.int32)[None], (B, S)
+            start + jnp.arange(S, dtype=jnp.int32)[None], (B, S)
         )
     else:
         positions = jnp.broadcast_to(

@@ -46,10 +46,13 @@ SPAN_NAMES = (
 #: backend decision of ``repro.kernels.dispatch.choose`` besides.
 #: ``tier.kv_host_windows`` counts KV windows transformed on the host.
 #: ``model.prefill_chunked`` counts the prefills whose attention scans
-#: query chunks (``models.layers.query_chunk``).
+#: query chunks (``models.layers.query_chunk``).  ``model.decode_batches``
+#: counts the scheduler's batched decode steps (``SlotCache``) and
+#: ``model.decode_rows`` the cache rows that decoded in them.
 COUNTER_NAMES = ("commit.d2h_bytes", "commit.copy_bytes", "commit.h2d_bytes",
                  "sched.tokens", "tier.kv_host_windows",
-                 "model.prefill_chunked")
+                 "model.prefill_chunked", "model.decode_batches",
+                 "model.decode_rows")
 
 _DECLARED = frozenset(SPAN_NAMES)
 

@@ -42,11 +42,13 @@ Continuous batching: :class:`ServeScheduler` adds request
 arrival/departure on top of that sharing — requests from a synthetic
 trace (:func:`repro.core.synth.request_trace`) wait FIFO for a batch
 slot, prefill on admission (gated on projected KV capacity), decode
-round-robin with whoever else is active, and retire at the commit
-boundary of their last token, freeing their pages and tier namespace
-(:meth:`ServeEngine.retire`) for the next queued request.  Per-sequence
-outputs stay bit-identical to solo runs under dynamic membership — the
-contract every piece of this module preserves.
+with whoever else is active, and retire at the commit boundary of their
+last token, freeing their pages and tier namespace
+(:meth:`ServeEngine.retire`) for the next queued request.  For a plain
+GQA ``k``/``v`` cache the scheduler's requests hold rows of one
+:class:`SlotCache`, and a tick decodes all of them in one jitted step.
+Per-sequence outputs stay bit-identical to solo runs under dynamic
+membership — the contract every piece of this module preserves.
 """
 
 from __future__ import annotations
@@ -74,6 +76,15 @@ from .paging import (
 # every engine — N streams of the same model trace and compile once, not
 # N times.
 _jit_step = jax.jit(decode_step, static_argnums=0)
+
+
+def _write_rows(full, rows, row):
+    """``rows`` ``(L, b, ...)`` into ``full`` ``(L, R, ...)`` from row
+    ``row`` on; ``full`` is donated, so the write is in place."""
+    return jax.lax.dynamic_update_slice_in_dim(full, rows, row, 1)
+
+
+_write_rows_jit = jax.jit(_write_rows, donate_argnums=0)
 
 #: Most tokens of completed KV windows that one device slice brings to the
 #: host.  A long prompt's commit pulls in slices of this size: on a v5e
@@ -114,8 +125,136 @@ class ServeStats:
         )
 
 
+@functools.lru_cache(maxsize=32)
+def slot_layout(cfg: ArchConfig) -> bool:
+    """Whether ``cfg``'s decode cache is the plain GQA ``k``/``v`` layout
+    whose rows :class:`SlotCache` batches.  A rolling window, leading
+    dense layers, a latent (MLA) or recurrent cache keeps one cache per
+    request."""
+    spec = jax.eval_shape(lambda: init_cache(cfg, 1, 8))
+    return set(spec) == {"layers"} and set(spec["layers"]) == {"k", "v"}
+
+
+class SlotCache:
+    """One decode cache of ``rows`` rows shared by a scheduler's requests.
+
+    Each request's engine holds ``batch`` rows from ``row`` on.  A decode
+    round stages every decoding row's token and position
+    (:meth:`stage`); the round's first :meth:`decode` call runs ONE jitted
+    ``decode_step`` over all rows with a ``(rows,)`` position vector, reads
+    the rows' last-position logits to the host once, and each call then
+    takes its own rows.  Rows that do not decode in a round (free slots,
+    requests that just emitted their last token) still compute, at their
+    parked position: at or past their valid length, where attention masks
+    it and the next prefill or decode overwrites it before any read.  The
+    step's shapes never change with the number of active requests, so it
+    compiles once per (config, rows, ``max_seq``).
+    """
+
+    def __init__(self, cfg: ArchConfig, params, rows: int, max_seq: int,
+                 batch: int = 1):
+        self.cfg = cfg
+        self.params = params
+        self.batch = batch
+        self.cache = init_cache(cfg, rows, max_seq)
+        self.parked = np.zeros((rows,), np.int32)
+        self._staged: Dict[int, Tuple[np.ndarray, int]] = {}
+        self._ready: Dict[int, np.ndarray] = {}
+
+    @property
+    def rows(self) -> int:
+        return self.parked.size
+
+    @property
+    def max_seq(self) -> int:
+        return self.cache["layers"]["k"].shape[2]
+
+    def grow(self, max_seq: int):
+        """Lengthen every row to ``max_seq`` positions (zeros past the
+        old end), for a scheduler whose sequence budget grew."""
+        pad = max_seq - self.max_seq
+        if pad > 0:
+            self.cache = jax.tree.map(
+                lambda a: jnp.pad(a, [(0, 0), (0, 0), (0, pad)]
+                                  + [(0, 0)] * (a.ndim - 3)), self.cache)
+
+    def read(self, row: int, kind: str, lo: int = 0,
+             n: Optional[int] = None) -> jax.Array:
+        """Device slice of ``batch`` rows from ``row`` of the leaf
+        ``kind``, token positions ``[lo, lo + n)`` (to the end without
+        ``n``)."""
+        leaf = self.cache["layers"][kind]
+        n = leaf.shape[2] - lo if n is None else n
+        start = (0, row, lo) + (0,) * (leaf.ndim - 3)
+        size = (leaf.shape[0], self.batch, n) + leaf.shape[3:]
+        return jax.lax.dynamic_slice(
+            leaf, [np.int32(i) for i in start], size)
+
+    def view(self, row: int) -> dict:
+        """The rows of one request as a cache of their own."""
+        return {"layers": {k: self.read(row, k)
+                           for k in self.cache["layers"]}}
+
+    def write(self, row: int, layers: dict):
+        """Write the leaves of ``layers`` (device or host, ``(L, batch,
+        max_seq, ...)``) into the rows from ``row``, one jitted in-place
+        row write per leaf."""
+        out = dict(self.cache["layers"])
+        for kind, rows in layers.items():
+            out[kind] = _write_rows_jit(out[kind], rows, np.int32(row))
+        self.cache = {"layers": out}
+
+    def park(self, row: int, pos: int):
+        """Where the rows from ``row`` compute in a step they do not
+        decode in: at their valid length ``pos``."""
+        self.parked[row : row + self.batch] = pos
+
+    def release(self, row: int):
+        self.park(row, 0)
+        self._staged.pop(row, None)
+        self._ready.pop(row, None)
+
+    def stage(self, row: int, tokens: np.ndarray, pos: int):
+        """Queue the rows from ``row`` to decode ``tokens`` (``(batch,
+        1)``) at ``pos`` in the next step."""
+        self._staged[row] = (np.asarray(tokens, np.int32).reshape(-1, 1), pos)
+
+    def decode(self, row: int, tokens: np.ndarray, pos: int) -> np.ndarray:
+        """The rows' next-token logits ``(batch, vocab)`` after decoding
+        ``tokens`` at ``pos``: from the step this round already ran, or
+        from a step run now over every staged row."""
+        if row not in self._ready:
+            self.stage(row, tokens, pos)
+            self._step()
+        return self._ready.pop(row)
+
+    def _step(self):
+        b = self.batch
+        tokens = np.zeros((self.rows, 1), np.int32)
+        for row, (tok, pos) in self._staged.items():
+            tokens[row : row + b] = tok
+            self.park(row, pos)
+        batch = {"tokens": jnp.asarray(tokens),
+                 "cache_pos": jnp.asarray(self.parked)}
+        with obs.span("model.decode"):
+            logits, self.cache = _jit_step(self.cfg, self.params, batch,
+                                           self.cache)
+        obs.count("model.decode_batches")
+        obs.count("model.decode_rows", len(self._staged) * b)
+        with obs.span("model.sync"):
+            host = np.asarray(logits[:, -1])
+        for row, (_, pos) in self._staged.items():
+            self._ready[row] = host[row : row + b]
+            self.park(row, pos + 1)
+        self._staged.clear()
+
+
 class ServeEngine:
-    """Single-host serving of one model with paged, tiered KV."""
+    """Single-host serving of one model with paged, tiered KV.
+
+    The engine owns a batch-``batch`` cache, or, given ``slots``, the
+    rows of a :class:`SlotCache` from ``row`` on; every read and write
+    of its KV goes through :meth:`_kv` and :meth:`_put_kv`."""
 
     def __init__(
         self,
@@ -134,6 +273,8 @@ class ServeEngine:
         prefix_index: Optional[PrefixShareIndex] = None,
         pnm_topk: Optional[int] = None,
         importance: str = "recency",
+        slots: Optional[SlotCache] = None,
+        row: int = 0,
     ):
         assert not cfg.is_encoder_only, "serving needs a decoder"
         if importance not in ("recency", "attention"):
@@ -161,7 +302,8 @@ class ServeEngine:
             key_prefix=key_prefix, sanitize=sanitize,
             prefix_index=prefix_index,
         )
-        self.cache = init_cache(cfg, batch, max_seq)
+        self.slots, self.row = slots, row
+        self.cache = None if slots is not None else init_cache(cfg, batch, max_seq)
         self.pos = 0
         # Prompt-prefix chain hashes (share-tagging completed prompt
         # windows); filled by the first prefill when the pool is wired to
@@ -177,14 +319,42 @@ class ServeEngine:
         self.tag = key_prefix.rstrip(".") or None
 
     # -- helpers ---------------------------------------------------------------
+    def _kinds(self) -> List[str]:
+        """The leaf names of the KV cache's ``layers``."""
+        src = self.cache if self.slots is None else self.slots.cache
+        return list(src.get("layers", {}))
+
+    def _kv(self, kind: str, lo: int = 0, n: Optional[int] = None):
+        """This engine's KV leaf ``kind`` on the device, ``(L, batch,
+        n, ...)`` for token positions ``[lo, lo + n)``, all of them
+        without ``n``."""
+        if self.slots is not None:
+            return self.slots.read(self.row, kind, lo, n)
+        leaf = self.cache["layers"][kind]
+        if n is None:
+            return leaf
+        return jax.lax.dynamic_slice_in_dim(leaf, np.int32(lo), n, axis=2)
+
+    def _put_kv(self, layers: Dict[str, np.ndarray]):
+        """Replace this engine's leaves ``layers`` (host, whole rows)."""
+        for v in layers.values():
+            obs.count("commit.h2d_bytes", v.nbytes)
+        if self.slots is not None:
+            self.slots.write(self.row, layers)
+            return
+        self.cache = dict(self.cache)
+        self.cache["layers"] = dict(self.cache["layers"])
+        for k, v in layers.items():
+            self.cache["layers"][k] = jnp.asarray(v)
+
     def _commit_pages(self, lo: int, hi: int):
         """Push completed KV windows [lo, hi) into the page pool."""
         with obs.span("commit.pages", self.tag):
             # Tickets issued at the previous boundary were in flight across the
             # decode step that just ran — apply their data before committing.
             self.flush_io()
-            layers = self.cache.get("layers", {})
-            kv_keys = [k for k in ("k", "v", "c_kv") if k in layers]
+            kinds = self._kinds()
+            kv_keys = [k for k in ("k", "v", "c_kv") if k in kinds]
             if not kv_keys:
                 return  # SSM/hybrid: constant-size state, nothing paged
             pt = self.page_tokens
@@ -205,8 +375,7 @@ class ServeEngine:
                 with obs.span("commit.pull"):
                     for kind in kv_keys:
                         # (L, B, n_tok, ...) bf16
-                        bufs[kind] = np.asarray(jax.lax.dynamic_slice_in_dim(
-                            layers[kind], np.int32(lo_tok), n_tok, axis=2))
+                        bufs[kind] = np.asarray(self._kv(kind, lo_tok, n_tok))
                         obs.count("commit.d2h_bytes", bufs[kind].nbytes)
                 for start in group:
                     for kind in kv_keys:
@@ -311,7 +480,7 @@ class ServeEngine:
         """f32 mean of the last committed window's rows for ``kind`` —
         the host-side stand-in for the current query direction that both
         the PNM gather and attention-mass importance score against."""
-        buf = np.asarray(self.cache["layers"][kind])
+        buf = np.asarray(self._kv(kind))
         channels = int(np.prod(buf.shape[3:])) if buf.ndim > 3 else 1
         lo = max(0, self.pos - self.page_tokens)
         win = buf[:, :, lo:self.pos]
@@ -329,15 +498,15 @@ class ServeEngine:
         query direction would spend on that page.  Keyed by
         ``(kind, layer, start)``; V pages inherit their K twin's mass
         (values move under the weights keys produce)."""
-        layers = self.cache.get("layers", {})
+        kinds = self._kinds()
         paged = (self.pos // self.page_tokens) * self.page_tokens
         masses: Dict[Tuple[str, int, int], float] = {}
         if paged <= 0:
             return masses
         for kind in ("k", "c_kv"):
-            if kind not in layers:
+            if kind not in kinds:
                 continue
-            buf = np.asarray(layers[kind])
+            buf = np.asarray(self._kv(kind))
             digest = self._query_digest(kind)
             n_layers = buf.shape[0]
             for layer in range(n_layers):
@@ -398,12 +567,11 @@ class ServeEngine:
         import ml_dtypes
 
         with obs.span("commit.apply", self.tag):
-            layers = dict(self.cache["layers"])
-            touched = False
+            layers: Dict[str, np.ndarray] = {}
             for page, u16 in zip(pages, data):
-                buf = layers[page.kind]
-                if not isinstance(buf, np.ndarray):
-                    buf = np.asarray(buf)
+                buf = layers.get(page.kind)
+                if buf is None:
+                    buf = np.asarray(self._kv(page.kind))
                     obs.count("commit.d2h_bytes", buf.nbytes)
                 target = buf[page.layer][:, page.start : page.start + self.page_tokens]
                 vals = u16.view(ml_dtypes.bfloat16).reshape(target.shape)
@@ -411,15 +579,8 @@ class ServeEngine:
                 obs.count("commit.copy_bytes", buf.nbytes)
                 buf[page.layer][:, page.start : page.start + self.page_tokens] = vals
                 layers[page.kind] = buf
-                touched = True
-            if touched:
-                self.cache = dict(self.cache)
-                self.cache["layers"] = {}
-                for k, v in layers.items():
-                    if isinstance(v, np.ndarray):
-                        obs.count("commit.h2d_bytes", v.nbytes)
-                        v = jnp.asarray(v)
-                    self.cache["layers"][k] = v
+            if layers:
+                self._put_kv(layers)
 
     # -- API ---------------------------------------------------------------------
     def prefill(self, tokens: np.ndarray) -> np.ndarray:
@@ -431,10 +592,18 @@ class ServeEngine:
             "cache_pos": jnp.int32(self.pos),
         }
         with obs.span("model.prefill", self.tag):
-            logits, self.cache = self._prefill(self.params, batch, self.cache)
+            if self.slots is None:
+                logits, self.cache = self._prefill(self.params, batch,
+                                                   self.cache)
+            else:
+                logits, cache = self._prefill(self.params, batch,
+                                              self.slots.view(self.row))
+                self.slots.write(self.row, cache["layers"])
         obs.count("model.prefill_chunked", int(query_chunk(S) > 0))
         old = self.pos
         self.pos += S
+        if self.slots is not None:
+            self.slots.park(self.row, self.pos)
         if old == 0 and self.pool.prefix_index is not None:
             self._share_hashes = prefix_chain_hashes(tokens, self.page_tokens)
             self._prompt_len = S
@@ -442,8 +611,21 @@ class ServeEngine:
         with obs.span("model.sync", self.tag):
             return np.asarray(logits[:, -1])
 
+    def stage(self, tokens: np.ndarray):
+        """Queue ``tokens`` (``(batch, 1)``) for the shared step of the
+        next decode round; nothing for an engine with its own cache."""
+        if self.slots is not None:
+            self.slots.stage(self.row, tokens, self.pos)
+
     def decode(self, tokens: np.ndarray) -> np.ndarray:
-        """tokens: (batch, 1) current token → next-token logits."""
+        """tokens: (batch, 1) current token → next-token logits.  On slot
+        rows the round's first call runs the step for every staged row."""
+        if self.slots is not None:
+            logits = self.slots.decode(self.row, tokens, self.pos)
+            old = self.pos
+            self.pos += 1
+            self._commit_pages(old, self.pos)
+            return logits
         batch = {
             "tokens": jnp.asarray(tokens.reshape(self.batch, 1)),
             "cache_pos": jnp.int32(self.pos),
@@ -512,6 +694,8 @@ class ServeEngine:
         batching's leave-at-commit-boundary).  Returns the number of tier
         keys freed.  The engine must not decode after retirement."""
         self.flush_io()
+        if self.slots is not None:
+            self.slots.release(self.row)
         return self.pool.release()
 
 
@@ -917,6 +1101,13 @@ class ServeScheduler:
     ``max_seq`` — joins, leaves and capacity stalls change receipts'
     latency (queue delay), never data.
 
+    Batched decode: where the model's cache is the plain GQA ``k``/``v``
+    layout (:func:`slot_layout`), the scheduler owns one
+    :class:`SlotCache` of ``max_batch * batch`` rows, each admitted
+    request's engine holds its slot's rows, and a tick decodes every
+    active request in one jitted step, reading the weights once.  Other
+    layouts keep one cache and one step per request.
+
     Modeled time: every scheduler tick costs
     ``max(1/cap_tok_s, tier I/O time of the tick)`` seconds — one batched
     decode round at the compute ceiling, or the tick's DRAM/link transfer
@@ -1015,6 +1206,7 @@ class ServeScheduler:
         self._ledger_mark = (0, 0)      # (raw, physical) at last correction
         self._kv_per_token: Optional[int] = None   # cached projection slope
         self._first_this_tick: List[RequestRecord] = []
+        self._slots: Optional[SlotCache] = None
         self._next_id = 0
         self._io_mark = self._io_snapshot()
 
@@ -1251,9 +1443,23 @@ class ServeScheduler:
                     break                    # strict FIFO: wait for retirements
                 self.pending.pop(0)
                 self.kv_committed_bytes += rec.kv_charged_bytes
-                self.active[free.pop(0)] = self._start(req, rec)
+                slot = free.pop(0)
+                self.active[slot] = self._start(req, rec, slot)
 
-    def _start(self, req: ServeRequest, rec: RequestRecord) -> _ActiveSeq:
+    def _slot_cache(self) -> Optional[SlotCache]:
+        """The shared slot cache, made at the first admission and grown
+        with ``max_seq``; ``None`` for a layout it cannot batch."""
+        if not slot_layout(self.cfg):
+            return None
+        if self._slots is None:
+            self._slots = SlotCache(self.cfg, self.params,
+                                    self.max_batch * self.batch,
+                                    self._max_seq, self.batch)
+        self._slots.grow(self._max_seq)
+        return self._slots
+
+    def _start(self, req: ServeRequest, rec: RequestRecord,
+               slot: int) -> _ActiveSeq:
         with obs.span("sched.engine_init", f"r{req.req_id}"):
             eng = ServeEngine(
                 self.cfg, self.params, max_seq=self._max_seq, batch=self.batch,
@@ -1262,6 +1468,7 @@ class ServeScheduler:
                 key_prefix=f"r{req.req_id}.", async_io=self.async_io,
                 prefix_index=self.prefix_index,
                 pnm_topk=self.pnm_topk, importance=self.importance,
+                slots=self._slot_cache(), row=slot * self.batch,
             )
         rec.admit_step = self.clock
         rec.t_admit_s = self.model_time_s
@@ -1271,6 +1478,10 @@ class ServeScheduler:
                           np.random.default_rng(req.seed), logits)
 
     def _decode_round(self):
+        """Sample every active request's next token, then decode those
+        that want another: on slot rows the first ``decode`` runs one
+        step for all of them, and each call commits its own pages."""
+        going = []
         for seq in self.active:
             if seq is None:
                 continue
@@ -1281,9 +1492,12 @@ class ServeScheduler:
             if len(seq.out) == 1:
                 self._first_this_tick.append(seq.record)
             if len(seq.out) < seq.req.max_new_tokens:
-                seq.logits = seq.engine.decode(nxt.reshape(-1, 1))
+                seq.engine.stage(nxt.reshape(-1, 1))
+                going.append((seq, nxt))
             else:
                 seq.done = True
+        for seq, nxt in going:
+            seq.logits = seq.engine.decode(nxt.reshape(-1, 1))
 
     def _advance_time(self):
         """One tick costs the compute ceiling or the tick's tier I/O,

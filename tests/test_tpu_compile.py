@@ -144,3 +144,30 @@ def test_qwen2_full_width_decode_step_compiles_for_v5e(one_chip,
                     "cache_pos": jax.ShapeDtypeStruct((), jnp.int32)})
     compiled = _jit_step.lower(QWEN, params, step, cache).compile()
     assert compiled.memory_analysis().argument_size_in_bytes < 16e9
+
+
+def test_qwen2_full_width_batched_decode_step_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """The scheduler's batched step at published widths: eight slot rows,
+    each at its own position, and the slot cache's in-place row write."""
+    from repro.models import init_cache, init_params
+    from repro.runtime.serving import _jit_step, _write_rows_jit
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    rows, max_seq = 8, 528
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(QWEN, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(QWEN, rows, max_seq)))
+    step = on_chip({"tokens": jax.ShapeDtypeStruct((rows, 1), jnp.int32),
+                    "cache_pos": jax.ShapeDtypeStruct((rows,), jnp.int32)})
+    compiled = _jit_step.lower(QWEN, params, step, cache).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < 16e9
+    full = cache["layers"]["k"]
+    one = on_chip(jax.ShapeDtypeStruct((full.shape[0], 1) + full.shape[2:],
+                                       full.dtype))
+    _write_rows_jit.lower(full, one, on_chip(
+        jax.ShapeDtypeStruct((), jnp.int32))).compile()
