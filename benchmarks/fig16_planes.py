@@ -10,7 +10,7 @@ import numpy as np
 
 from repro.core import synth
 from repro.core.bitplane import pack_planes
-from repro.core.codec import compress_block
+from repro.core.codec import compress_slab
 from repro.core.kv_transform import kv_forward
 
 from .common import emit
@@ -23,11 +23,12 @@ def _plane_ratios(u16: np.ndarray) -> list[float]:
     flat = u16.ravel()
     for s in range(0, flat.size - 2047, 2048):
         planes = pack_planes(flat[s : s + 2048])
-        for p in range(16):
-            raw = planes[p].tobytes()
-            comp, _ = compress_block(raw, "zstd")
-            total_raw[p] += len(raw)
-            total_comp[p] += len(comp)
+        n = planes.shape[1]
+        bounds = np.arange(17) * n
+        comps, _ = compress_slab(planes.reshape(-1), bounds[:-1], bounds[1:],
+                                 "zstd")
+        total_raw += n
+        total_comp += [len(c) for c in comps]
     return list(total_raw / np.maximum(total_comp, 1))
 
 

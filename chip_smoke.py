@@ -211,7 +211,6 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     print(f"[device] kind={devs[0].device_kind} count={len(devs)} "
           f"compile_cache={cache_dir}")
-    assert not codec._scalar_lz4_forced(), "TRACE_SCALAR_LZ4 pins the oracle"
 
     cfg, params = model(smoke=False, seed=args.seed)
     print(f"[model] {ARCH}: {cfg.n_layers} layers, d_model {cfg.d_model}, "

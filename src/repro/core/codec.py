@@ -5,41 +5,34 @@ the codec input (plane streams instead of mixed-field words), not from a
 bespoke compressor.  We model the paper's two codecs:
 
 * ``lz4`` — a from-scratch LZ4 *block format* encoder/decoder (the offline
-  environment has no lz4 binding).  Greedy hash-chain matching, standard
-  end-of-block rules, byte-exact round-trip; this stands in for the 32-lane
-  streaming LZ4 engine of the controller (paper §IV-E).
+  environment has no lz4 binding).  The match search runs as one array
+  program over a whole slab of streams (``kernels.lz4.match_events_slab``:
+  pallas on a TPU, vectorized numpy elsewhere) and :func:`lz4_emit_events`
+  serializes its events to standard LZ4 blocks; this stands in for the
+  32-lane streaming LZ4 engine of the controller (paper §IV-E).
 * ``zstd`` — the real Zstandard via the ``zstandard`` package.
 
-Both are exposed through a tiny registry with block-level *bypass*: when a
-block is incompressible the device stores it raw and marks the index entry
-(paper §III-D "Bypass and correctness invariants").  The bypass decision is
-two-stage: a cheap sampled entropy pre-screen routes near-certainly
-incompressible blocks to raw storage *before* paying for compression (the
-controller's line-rate engines do the same to avoid stalling on
-high-entropy planes), and blocks that do run the codec fall back to raw
-when the payload fails :data:`BYPASS_THRESHOLD`.
+:func:`compress_slab` is the one compress entry point.  It takes a flush
+group's streams as (start, end) ranges of one flat byte slab and applies
+block-level *bypass*: when a block is incompressible the device stores it
+raw and marks the index entry (paper §III-D "Bypass and correctness
+invariants").  The bypass decision is two-stage: a cheap sampled entropy
+pre-screen routes near-certainly incompressible blocks to raw storage
+*before* paying for compression (the controller's line-rate engines do
+the same to avoid stalling on high-entropy planes), and blocks that do
+run the codec fall back to raw when the payload fails
+:data:`BYPASS_THRESHOLD`.
 
-The write path is batched: :func:`compress_batch` compresses a flush
-group's blocks in one pass — for LZ4 the 4-byte words and their hashes are
-precomputed over the whole concatenated slab in vectorized numpy (the
-per-block emit loop then just walks precomputed arrays), and for zstd the
-group goes through the library's multi-frame API when available.  Payloads
-are byte-identical to per-block :func:`compress_block` calls by
-construction (per-block hash tables, per-block emit), which the encode
-differential tests assert.
-
-Contract: every compressed payload round-trips byte-exactly
-(``decompress_block(compress_block(x)) == x``); batch entry points are
-byte-identical per block to their scalar counterparts; a ``RAW`` flag
-always means the stored payload IS the input bytes.  Callers (the layout
-strategies in ``core.tier``) rely on all three.
+Contract: every compressed payload round-trips byte-exactly through
+:func:`decompress_batch`, and a ``RAW`` flag always means the stored
+payload IS the input bytes.  Callers (the layout strategies in
+``core.tier``) rely on both.  The per-block scalar encoder that the
+stored bytes are held to lives with the tests (``tests/encode_ref.py``).
 """
 
 from __future__ import annotations
 
-import os
 import warnings
-from bisect import bisect_left
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -56,11 +49,16 @@ except (ImportError, AttributeError):  # pragma: no cover - optional dep
 
 HAVE_ZSTD = _zstd is not None
 
+# LZ4 match policy, shared by the kernel (asserted in _lz4_kernel) and
+# the tests' scalar reference encoder.
 _HASH_LOG = 13
-_HASH_SIZE = 1 << _HASH_LOG
 _MIN_MATCH = 4
 _MFLIMIT = 12          # match must not start within last 12 bytes
 _LAST_LITERALS = 5     # last 5 bytes are always literals
+_RUN_STRIDE = 4        # interior byte-run positions keep a candidate only
+                       # every _RUN_STRIDE bytes: candidates stay ~N/4 on
+                       # zero-heavy planes while a match ending mid-run
+                       # re-anchors within at most 3 literal bytes
 
 
 class CorruptPayloadError(ValueError):
@@ -78,328 +76,12 @@ class CorruptPayloadError(ValueError):
 # LZ4 block format
 # ---------------------------------------------------------------------------
 
-def _lz4_hash(seq_u32: int) -> int:
-    return (seq_u32 * 2654435761) >> (32 - _HASH_LOG) & (_HASH_SIZE - 1)
-
-
-def _lz4_words_hashes(buf: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Vectorised 4-byte little-endian words + hashes for every position.
-
-    ``buf`` may be a whole encode slab: per-block hash/emit loops only ever
-    touch positions whose 4-byte window lies inside their own block, so the
-    precompute can be shared across a batch (see :func:`lz4_compress_batch`).
-    """
-    w = (
-        buf[:-3].astype(np.uint32)
-        | (buf[1:-2].astype(np.uint32) << 8)
-        | (buf[2:-1].astype(np.uint32) << 16)
-        | (buf[3:].astype(np.uint32) << 24)
-    )
-    hashes = ((w * np.uint32(2654435761)) >> np.uint32(32 - _HASH_LOG)).astype(
-        np.int64
-    )
-    return w, hashes
-
-
-_MATCH_CAP = 64        # vectorized-LCP sweep bound for offsets > 1; NOT an
-                       # output cap — selected matches that reach it are
-                       # extended to the true LCP by galloping (offset-1
-                       # byte runs extend uncapped via the run table), so
-                       # emitted matches equal the scalar scan's exactly
-_RUN_STRIDE = 4        # interior byte-run positions keep a candidate only
-                       # every _RUN_STRIDE bytes: candidates stay ~N/4 on
-                       # zero-heavy planes while a match ending mid-run
-                       # re-anchors within at most 3 literal bytes
-
-
-def _emit_seq(out: bytearray, data: bytes, lit_start: int, lit_end: int,
-              mlen: int, dist: int):
-    """Append one LZ4 sequence (token, literal run, optional match) —
-    the general path with 255-extension chains; ``mlen == 0`` emits the
-    end-of-block literal-only sequence."""
-    append = out.append
-    lit_len = lit_end - lit_start
-    tok_lit = min(lit_len, 15)
-    tok_match = min(mlen - _MIN_MATCH, 15) if mlen else 0
-    append((tok_lit << 4) | tok_match)
-    rest = lit_len - 15
-    while rest >= 0:
-        append(min(rest, 255))
-        if rest < 255:
-            break
-        rest -= 255
-    out += data[lit_start:lit_end]
-    if mlen:
-        append(dist & 0xFF)
-        append(dist >> 8)
-        rest = mlen - _MIN_MATCH - 15
-        while rest >= 0:
-            append(min(rest, 255))
-            if rest < 255:
-                break
-            rest -= 255
-
-
-def _lz4_emit(data: bytes, events, out: bytearray):
-    """Serialize match ``events`` over ``data`` in LZ4 block format.
-
-    ``events`` is an ascending list of ``(pos, dist, mlen)``; everything
-    between events is literals, and the block ends in a literal-only
-    sequence (the standard end-of-block rule).
-    """
-    n = len(data)
-    anchor = 0
-    for pos, dist, mlen in events:
-        _emit_seq(out, data, anchor, pos, mlen, dist)
-        anchor = pos + mlen
-    _emit_seq(out, data, anchor, n, 0, 0)
-
-
-def _lz4_events_scalar(data: bytes) -> list:
-    """Reference match scan for one block — sequential python.
-
-    The algorithm (shared bit-for-bit with the vectorized batch scan):
-    every position feeds a last-occurrence hash table; a position ``i``
-    outside any selected match starts a match when its table candidate
-    has the same 4-byte word within the 64 KiB window; matches extend by
-    LCP, bounded by the end-of-block literal rules.  Offset-1
-    candidates are honoured only at a run's FIRST interior position
-    (``data[i-2] != data[i-1]``): one uncapped match covers the whole
-    run, and skipping the interior keeps the batch scan's candidate set
-    proportional to runs, not bytes.
-    """
-    n = len(data)
-    events: list = []
-    if n < _MFLIMIT + 1:
-        return events
-    w_np, h_np = _lz4_words_hashes(np.frombuffer(data, dtype=np.uint8))
-    w, hashes = w_np.tolist(), h_np.tolist()
-    table = [-1] * _HASH_SIZE
-    limit = n - _MFLIMIT
-    cur_end = 0
-    for i in range(n - 3):
-        h = hashes[i]
-        cand = table[h]
-        table[h] = i
-        if (i >= limit or i < cur_end or cand < 0
-                or i - cand > 0xFFFF or w[cand] != w[i]):
-            continue
-        dist = i - cand
-        if (dist == 1 and i >= 2 and data[i - 2] == data[i - 1]
-                and i % _RUN_STRIDE):
-            continue          # run interior: covered by run-first / stride
-        max_len = n - _LAST_LITERALS - i
-        mlen = _MIN_MATCH
-        while (mlen + 32 <= max_len
-               and data[cand + mlen : cand + mlen + 32]
-               == data[i + mlen : i + mlen + 32]):
-            mlen += 32
-        while mlen < max_len and data[cand + mlen] == data[i + mlen]:
-            mlen += 1
-        events.append((i, dist, mlen))
-        cur_end = i + mlen
-    return events
-
-
-def lz4_compress(data: bytes) -> bytes:
-    """LZ4 block-format compression (pure python + numpy hashing)."""
-    if len(data) == 0:
-        return b"\x00"
-    out = bytearray()
-    _lz4_emit(data, _lz4_events_scalar(data), out)
-    return bytes(out)
-
-
-def _lz4_compress_slab(buf: np.ndarray, chunks: Sequence[bytes]) -> List[bytes]:
-    """Vectorized match scan + fused greedy emit over a concatenated slab.
-
-    One word/hash pass, one previous-occurrence argsort, one run-boundary
-    scan and one (capped) LCP sweep serve every block of the batch; only
-    the final greedy selection walks SELECTED matches in python, emitting
-    each sequence as it is chosen (no event materialization).  Per block
-    the output is byte-identical to :func:`lz4_compress`: the
-    previous-occurrence keys are namespaced by block id, so candidates can
-    never cross a block boundary, exactly like the per-block hash table.
-    """
-    N = int(buf.size)
-    B = len(chunks)
-    def _all_literals() -> List[bytes]:
-        outs = []
-        for data in chunks:
-            blk = bytearray()
-            _lz4_emit(data, [], blk)
-            outs.append(bytes(blk) if data else b"\x00")
-        return outs
-    if N < _MIN_MATCH:
-        return _all_literals()
-    w, h = _lz4_words_hashes(buf)
-    sizes_a = np.asarray([len(c) for c in chunks], dtype=np.int64)
-    ends = np.cumsum(sizes_a)
-    starts_a = ends - sizes_a
-    # positions whose 4-byte word lies inside their own block: everything
-    # except the (up to) 3 positions before each block boundary
-    mask = np.ones(N - 3, dtype=bool)
-    cols = (ends[:, None] - np.arange(3, 0, -1)[None, :]).ravel()
-    cols = cols[(cols >= np.repeat(starts_a, 3)) & (cols >= 0)
-                & (cols < N - 3)]
-    mask[cols] = False
-    wvalid = np.flatnonzero(mask)
-    if wvalid.size == 0:
-        return _all_literals()
-    # previous same-hash occurrence within the block = last-occurrence
-    # hash table, computed for all positions at once (int32 keys sort
-    # measurably faster and hold block_id * 8192 + hash comfortably)
-    blk_w = np.searchsorted(ends, wvalid, side="right")
-    keys = (blk_w * np.int64(_HASH_SIZE) + h[wvalid]).astype(
-        np.int32 if B * _HASH_SIZE < (1 << 31) else np.int64
-    )
-    order = np.argsort(keys, kind="stable")      # stable: ascending pos
-    sp = wvalid[order]
-    same = keys[order][1:] == keys[order][:-1]
-    prev = np.full(N - 3, -1, dtype=np.int64)
-    prev[sp[1:][same]] = sp[:-1][same]
-
-    g = np.flatnonzero(prev >= 0)
-    cand = prev[g]
-    blk_g = np.searchsorted(ends, g, side="right")
-    local_g = g - starts_a[blk_g]
-    nb_g = sizes_a[blk_g]
-    ok = ((local_g < nb_g - _MFLIMIT)
-          & (g - cand <= 0xFFFF)
-          & (w[g] == w[cand]))
-    # interior of a byte run: covered by the run-first candidate's
-    # uncapped match (same rule as the scalar scan) — dropping all but
-    # every _RUN_STRIDE-th keeps the candidate set ~N/4 instead of N on
-    # zero-heavy planes, while matches that end mid-run re-anchor within
-    # at most _RUN_STRIDE-1 literal bytes
-    ok &= ~((g - cand == 1) & (local_g >= 2) & (buf[g - 2] == buf[g - 1])
-            & (local_g % _RUN_STRIDE != 0))
-    keep = np.flatnonzero(ok)
-    g, cand, blk_g, local_g, nb_g = (
-        g[keep], cand[keep], blk_g[keep], local_g[keep], nb_g[keep])
-    if g.size == 0:
-        return _all_literals()
-    dist = g - cand
-    max_len = nb_g - _LAST_LITERALS - local_g
-    mlen = np.full(g.size, _MIN_MATCH, dtype=np.int64)
-
-    run = dist == 1
-    if run.any():
-        # offset-1 = byte run: LCP is the run length, read off the
-        # run-boundary table instead of byte-compare loops
-        bnd = np.flatnonzero(buf[1:] != buf[:-1])    # last index of each run
-        if bnd.size:
-            idx = np.searchsorted(bnd, g[run] - 1, side="left")
-            rend = np.where(idx < bnd.size,
-                            bnd[np.minimum(idx, bnd.size - 1)], N - 1)
-        else:
-            rend = np.full(int(run.sum()), N - 1, dtype=np.int64)
-        mlen[run] = np.minimum(rend - g[run] + 1, max_len[run])
-    gen = np.flatnonzero(~run)
-    if gen.size:
-        # LCP sweep, word-stride: compare 4 bytes per pass via the word
-        # array (w[x] = bytes x..x+3, in-block by the cap bound); a failed
-        # word resolves its 0-3 leading equal bytes exactly, survivors
-        # that run out of word room finish in the byte phase below.
-        cap = np.minimum(max_len[gen], _MATCH_CAP)
-        gg, cc = g[gen], cand[gen]
-        ml = np.full(gen.size, _MIN_MATCH, dtype=np.int64)
-        k = _MIN_MATCH
-        alive = np.arange(gen.size)
-        partial: List[np.ndarray] = []       # ran out of word room at ml=k
-        while True:
-            word_ok = cap[alive] >= k + 4
-            if not word_ok.all():
-                partial.append(alive[~word_ok])
-                alive = alive[word_ok]
-            if alive.size == 0:
-                break
-            eqw = w[gg[alive] + k] == w[cc[alive] + k]
-            fail = alive[~eqw]
-            if fail.size:
-                b0 = (buf[gg[fail] + k] == buf[cc[fail] + k]).astype(np.int64)
-                b1 = b0 & (buf[gg[fail] + k + 1] == buf[cc[fail] + k + 1])
-                b2 = b1 & (buf[gg[fail] + k + 2] == buf[cc[fail] + k + 2])
-                ml[fail] = k + b0 + b1 + b2
-            alive = alive[eqw]
-            k += 4
-            ml[alive] = k
-        # byte phase: at most 3 bytes of per-element room left
-        arr = np.concatenate(partial) if partial else alive
-        while arr.size:
-            arr = arr[cap[arr] > ml[arr]]
-            if arr.size == 0:
-                break
-            eq = buf[gg[arr] + ml[arr]] == buf[cc[arr] + ml[arr]]
-            arr = arr[eq]
-            ml[arr] += 1
-        mlen[gen] = ml
-
-    # Greedy left-to-right selection fused with emit.  bisect skips the
-    # candidates a selected match covers in O(log) instead of walking
-    # them, so this loop runs once per EMITTED match, not once per
-    # candidate; dist/mlen are only materialized for matches that are
-    # actually selected, and each sequence is serialized as it is chosen.
-    # Selected matches whose sweep hit _MATCH_CAP gallop out to the true
-    # LCP here — selected matches never overlap, so total galloping work
-    # is bounded by the slab size.
-    pos_l = local_g.tolist()
-    b_lo = np.searchsorted(blk_g, np.arange(B), side="left").tolist()
-    b_hi = np.searchsorted(blk_g, np.arange(B), side="right").tolist()
-    dist_i = dist.item
-    mlen_i = mlen.item
-    sizes_l = sizes_a.tolist()
-    outs: List[bytes] = []
-    for blk in range(B):
-        data = chunks[blk]
-        n = sizes_l[blk]
-        if n == 0:
-            outs.append(b"\x00")
-            continue
-        i, hi = b_lo[blk], b_hi[blk]
-        out = bytearray()
-        append = out.append
-        anchor = 0
-        while i < hi:
-            p = pos_l[i]
-            m = mlen_i(i)
-            d = dist_i(i)
-            if m == _MATCH_CAP and d != 1:
-                c = p - d
-                max_len = n - _LAST_LITERALS - p
-                while (m + 32 <= max_len
-                       and data[c + m : c + m + 32]
-                       == data[p + m : p + m + 32]):
-                    m += 32
-                while m < max_len and data[c + m] == data[p + m]:
-                    m += 1
-            lit = p - anchor
-            if lit < 15 and m < 19:
-                # fast path: single token byte, no extension chains
-                append((lit << 4) | (m - _MIN_MATCH))
-                out += data[anchor:p]
-                append(d & 0xFF)
-                append(d >> 8)
-            else:
-                _emit_seq(out, data, anchor, p, m, d)
-            anchor = p + m
-            i = bisect_left(pos_l, anchor, i + 1, hi)
-        lit = n - anchor
-        if lit < 15:
-            append(lit << 4)
-            out += data[anchor:]
-        else:
-            _emit_seq(out, data, anchor, n, 0, 0)
-        outs.append(bytes(out))
-    return outs
-
-
 def _lz4_kernel():
     """The ``kernels.lz4`` match engine.
 
     The kernel and this module each carry the LZ4 policy constants; a
-    drift would silently break kernel-vs-oracle byte identity, so it is
-    asserted at dispatch.
+    drift would silently break kernel-vs-reference byte identity, so it
+    is asserted at dispatch.
     """
     from ..kernels import lz4 as _k
 
@@ -408,14 +90,8 @@ def _lz4_kernel():
                                _LAST_LITERALS, _RUN_STRIDE):
         raise RuntimeError(
             "kernels.lz4 match-policy constants diverged from "
-            "core.codec's scalar reference")
+            "core.codec's")
     return _k
-
-
-def _scalar_lz4_forced() -> bool:
-    """``TRACE_SCALAR_LZ4=1`` pins the PR 3 fused slab encoder — the
-    parity oracle the kernel path is differential-tested against."""
-    return os.environ.get("TRACE_SCALAR_LZ4", "") not in ("", "0")
 
 
 def lz4_emit_events(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
@@ -435,7 +111,6 @@ def lz4_emit_events(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
     source/length, output offset via one cumsum) and scatters token
     bytes, 255-extension chains, literals and offset words with ragged
     numpy fills — O(output bytes) C-speed work, no per-match python.
-    Byte-identical to :func:`_lz4_emit` over each stream's events.
     """
     S = int(starts.size)
     E = int(pos.size)
@@ -526,14 +201,8 @@ def lz4_emit_events(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
 def _lz4_slab_streams(buf: np.ndarray, starts: np.ndarray,
                       ends: np.ndarray,
                       force: Optional[str] = None) -> List[bytes]:
-    """LZ4-compress the addressed streams of a uint8 slab, kernel path
-    first.  ``TRACE_SCALAR_LZ4=1`` pins the PR 3 fused slab encoder
-    instead (the parity oracle).
-    """
-    if _scalar_lz4_forced():
-        chunks = [buf[s:e].tobytes() for s, e in zip(starts, ends)]
-        return _lz4_compress_slab(
-            np.frombuffer(b"".join(chunks), dtype=np.uint8), chunks)
+    """LZ4-compress the addressed streams of a uint8 slab: the match
+    kernel's events, serialized by :func:`lz4_emit_events`."""
     starts = np.asarray(starts, dtype=np.int64).ravel()
     ends = np.asarray(ends, dtype=np.int64).ravel()
     gapped = bool(starts.size) and (
@@ -552,26 +221,6 @@ def _lz4_slab_streams(buf: np.ndarray, starts: np.ndarray,
                                                           force=force)
     with obs.span("tier.lz4_emit"):
         return lz4_emit_events(buf, starts, ends, pos, dist, mlen)
-
-
-def lz4_compress_batch(chunks: Sequence[bytes],
-                       force: Optional[str] = None) -> List[bytes]:
-    """Compress a batch of blocks in a few vectorized passes.
-
-    Byte-identical to mapping :func:`lz4_compress` over ``chunks`` (the
-    differential encode tests assert this).  The match scan runs as one
-    array program over the concatenated slab (``kernels.lz4`` — pallas on
-    accelerator backends, vectorized numpy elsewhere) and the token emit
-    is one ragged scatter (:func:`lz4_emit_events`); ``TRACE_SCALAR_LZ4=1``
-    pins the previous fused slab encoder as a parity oracle.
-    """
-    if not chunks:
-        return []
-    sizes = np.asarray([len(c) for c in chunks], dtype=np.int64)
-    ends = np.cumsum(sizes)
-    starts = ends - sizes
-    buf = np.frombuffer(b"".join(chunks), dtype=np.uint8)
-    return _lz4_slab_streams(buf, starts, ends, force=force)
 
 
 def lz4_decompress(comp: bytes, max_out: int | None = None) -> bytes:
@@ -685,16 +334,29 @@ def zstd_decompress(data: bytes, max_out: int | None = None) -> bytes:
     return _ZSTD_D.decompress(data, max_output_size=max_out or 0)
 
 
+def _raw_streams(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
+                 force: Optional[str] = None) -> List[bytes]:
+    return [buf[s:e].tobytes() for s, e in zip(starts, ends)]
+
+
+def _zstd_streams(buf: np.ndarray, starts: np.ndarray, ends: np.ndarray,
+                  force: Optional[str] = None) -> List[bytes]:
+    return zstd_compress_batch(_raw_streams(buf, starts, ends))
+
+
 # ---------------------------------------------------------------------------
-# Registry + block API with bypass
+# Registry + slab API with bypass
 # ---------------------------------------------------------------------------
 
-CODECS: Dict[str, Tuple[Callable[[bytes], bytes], Callable[..., bytes]]] = {
-    "lz4": (lz4_compress, lz4_decompress),
-    "none": (lambda b: b, lambda b, max_out=None: b),
+# name -> (slab compressor, decompressor).  A slab compressor maps
+# ``(buf, starts, ends, force)`` to one payload per addressed stream;
+# ``force`` pins the LZ4 match kernel's path and is ignored by the rest.
+CODECS: Dict[str, Tuple[Callable[..., List[bytes]], Callable[..., bytes]]] = {
+    "lz4": (_lz4_slab_streams, lz4_decompress),
+    "none": (_raw_streams, lambda b, max_out=None: b),
 }
 if HAVE_ZSTD:
-    CODECS["zstd"] = (zstd_compress, zstd_decompress)
+    CODECS["zstd"] = (_zstd_streams, zstd_decompress)
 
 _warned_fallback = False
 
@@ -749,9 +411,11 @@ def _prescreen_group(rows: np.ndarray) -> np.ndarray:
     """Vectorized pre-screen over a ``(R, n)`` uint8 matrix of same-length
     blocks → boolean bypass decision per row.
 
-    Single source of truth: the scalar :func:`prescreen_bypass` wraps this
-    with ``R = 1``, so the scalar and batched encoders cannot diverge on a
-    threshold-boundary rounding difference.
+    Deterministic (stride sampling, no RNG) and independent per row.
+    False negatives only cost a wasted compression attempt; false
+    positives would change stored bytes, so both statistics are
+    thresholded conservatively.  The tests' per-block reference calls
+    this with ``R = 1``, so the two cannot diverge on a threshold.
     """
     R, n = rows.shape
     sample = rows[:, :: max(1, n // _PRESCREEN_BYTES)][:, :_PRESCREEN_BYTES]
@@ -787,42 +451,12 @@ def _prescreen_group(rows: np.ndarray) -> np.ndarray:
     return out
 
 
-def prescreen_bypass(data: bytes) -> bool:
-    """True when ``data`` is near-certainly incompressible (sampled test).
-
-    Deterministic (stride sampling, no RNG) so scalar and batched encoders
-    agree block-for-block.  False negatives only cost a wasted compression
-    attempt; false positives would change stored bytes, so both statistics
-    are thresholded conservatively.
-    """
-    if len(data) < _PRESCREEN_MIN_LEN:
-        return False
-    return bool(_prescreen_group(
-        np.frombuffer(data, dtype=np.uint8).reshape(1, -1))[0])
-
-
-def _prescreen_batch(chunks: Sequence[bytes]) -> List[bool]:
-    """Per-block bypass decisions for a batch — identical to mapping
-    :func:`prescreen_bypass`, but same-length blocks (the common case: a
-    plane stream per 4 KB block) share one vectorized pass."""
-    res = [False] * len(chunks)
-    by_len: Dict[int, List[int]] = {}
-    for i, ch in enumerate(chunks):
-        if len(ch) >= _PRESCREEN_MIN_LEN:
-            by_len.setdefault(len(ch), []).append(i)
-    for n, idxs in by_len.items():
-        rows = np.frombuffer(
-            b"".join(chunks[i] for i in idxs), dtype=np.uint8
-        ).reshape(len(idxs), n)
-        for i, ok in zip(idxs, _prescreen_group(rows)):
-            res[i] = bool(ok)
-    return res
-
-
 def _prescreen_slab(buf: np.ndarray, starts: np.ndarray,
                     ends: np.ndarray) -> np.ndarray:
-    """:func:`_prescreen_batch` over slab-addressed streams — same-length
-    streams gather into one ``(R, n)`` matrix, no bytes materialized."""
+    """Per-stream bypass decisions for slab-addressed streams: streams of
+    at least ``_PRESCREEN_MIN_LEN`` bytes gather, by length, into one
+    ``(R, n)`` matrix for :func:`_prescreen_group`; shorter ones always
+    run the codec."""
     sizes = ends - starts
     res = np.zeros(starts.size, dtype=bool)
     for n in np.unique(sizes):
@@ -834,69 +468,20 @@ def _prescreen_slab(buf: np.ndarray, starts: np.ndarray,
     return res
 
 
-def compress_block(data: bytes, codec: str) -> tuple[bytes, int]:
-    """Compress one block; fall back to raw storage when incompressible.
-
-    Returns ``(payload, flag)`` with flag ∈ {RAW, COMPRESSED}.  The bypass
-    decision (pre-screen + :data:`BYPASS_THRESHOLD`) is shared with
-    :func:`compress_batch`, so the two are byte-identical per block.
-    """
-    if prescreen_bypass(data):
-        return data, RAW
-    c, _ = CODECS[resolve_codec(codec)]
-    comp = c(data)
-    if len(comp) >= BYPASS_THRESHOLD * len(data):
-        return data, RAW
-    return comp, COMPRESSED
-
-
-def compress_batch(chunks: Sequence[bytes],
-                   codec: str) -> Tuple[List[bytes], List[int]]:
-    """Compress a flush group of blocks in a few vectorized passes.
-
-    Semantically ``zip(*[compress_block(c, codec) for c in chunks])`` —
-    byte-identical payloads and flags — but the pre-screen routes
-    incompressible blocks out before compression, and the surviving blocks
-    share one precompute (LZ4 slab words/hashes, zstd multi-frame call)
-    instead of paying per-block numpy/library overhead.
-    """
-    name = resolve_codec(codec)
-    payloads: List[bytes] = [b""] * len(chunks)
-    flags: List[int] = [RAW] * len(chunks)
-    todo: List[int] = []
-    for i, skip in enumerate(_prescreen_batch(chunks)):
-        if skip:
-            payloads[i] = chunks[i]
-        else:
-            todo.append(i)
-    if todo:
-        if name == "lz4":
-            comps = lz4_compress_batch([chunks[i] for i in todo])
-        elif name == "zstd":
-            comps = zstd_compress_batch([chunks[i] for i in todo])
-        else:
-            c, _ = CODECS[name]
-            comps = [c(chunks[i]) for i in todo]
-        for i, comp in zip(todo, comps):
-            if len(comp) >= BYPASS_THRESHOLD * len(chunks[i]):
-                payloads[i] = chunks[i]
-            else:
-                payloads[i], flags[i] = comp, COMPRESSED
-    return payloads, flags
-
-
 def compress_slab(slab, starts: Sequence[int], ends: Sequence[int],
                   codec: str,
                   force: Optional[str] = None) -> Tuple[List[bytes], List[int]]:
-    """:func:`compress_batch` over streams addressed INSIDE a flat slab.
+    """Compress a flush group's streams, addressed INSIDE a flat slab.
 
     ``slab`` is a flat uint8 buffer, e.g. the ravelled planes of
     ``pack_planes_slab``; ``starts[i]:ends[i]`` bounds stream ``i``.
-    Byte-identical payloads and flags to
-    ``compress_batch([slab[s:e] ...], codec)``, but raw /
-    bypassed payloads are sliced from the slab and LZ4 streams go to the
-    kernel as (start, end) bounds — no per-stream bytes are materialized
-    before the bypass decision.
+    Returns one ``(payload, flag)`` per stream, as two lists, with flag ∈
+    {RAW, COMPRESSED}.  The pre-screen routes incompressible streams to
+    raw storage first; the surviving streams go to the codec's slab
+    compressor in one call (LZ4: the match kernel over (start, end)
+    bounds, no per-stream bytes materialized), and a payload that fails
+    :data:`BYPASS_THRESHOLD` is stored raw.  ``force`` pins the LZ4
+    match kernel's path (``"numpy"`` / ``"device"``).
     """
     name = resolve_codec(codec)
     starts = np.asarray(starts, dtype=np.int64).ravel()
@@ -915,15 +500,8 @@ def compress_slab(slab, starts: Sequence[int], ends: Sequence[int],
             todo.append(i)
     if todo:
         tsel = np.asarray(todo, dtype=np.int64)
-        if name == "lz4":
-            comps = _lz4_slab_streams(buf, starts[tsel], ends[tsel],
-                                      force=force)
-        elif name == "zstd":
-            comps = zstd_compress_batch(
-                [buf[starts[i]: ends[i]].tobytes() for i in todo])
-        else:
-            c, _ = CODECS[name]
-            comps = [c(buf[starts[i]: ends[i]].tobytes()) for i in todo]
+        compress, _ = CODECS[name]
+        comps = compress(buf, starts[tsel], ends[tsel], force)
         for i, comp in zip(todo, comps):
             n = int(ends[i] - starts[i])
             if len(comp) >= BYPASS_THRESHOLD * n:
@@ -943,7 +521,7 @@ def decompress_block(payload: bytes, flag: int, codec: str, orig_len: int) -> by
 
 def decompress_batch(payloads: Sequence[bytes], flags: Sequence[int],
                      codec: str, orig_lens: Sequence[int]) -> List[bytes]:
-    """Inverse of :func:`compress_batch`: one codec resolve for the group."""
+    """Inverse of :func:`compress_slab`: one codec resolve for the group."""
     _, d = CODECS[resolve_codec(codec)]
     return [
         pay if fl == RAW else d(pay, max_out=n)

@@ -29,9 +29,9 @@ one encode slab and encodes it in a few vectorized passes — per
 128K-element slab one KV transform and plane pack (one device program
 on a TPU) and ONE ``codec.compress_slab`` over every (plane, block)
 stream — instead of O(blocks x planes) python-level calls (see
-``Layout.encode_batch`` / ``TierStore._post_writes``).  The per-block pipeline survives as
-``Layout.encode_batch_scalar`` (``TierStore(batched_encode=False)``),
-byte-identical by the encode differential tests.
+``Layout.encode_batch`` / ``TierStore._post_writes``).  The encode tests
+hold every layout's stored bytes to a per-block reference pipeline that
+lives with the tests.
 
 Accounting conventions (per read):
   * ``dram_bytes``  — bytes the device DRAM actually serves (compressed
@@ -139,11 +139,10 @@ from .bitplane import (
     BF16_BITS,
     BLOCK_ELEMS,
     iter_blocks,
-    pack_planes,
     unpack_planes_subset,
 )
 from .kv_transform import (
-    KVBlockMeta, kv_forward, kv_forward_batch, kv_inverse_batch,
+    KVBlockMeta, kv_forward_batch, kv_inverse_batch,
 )
 from .precision import EXP_BITS, PrecisionView, FULL, SCORE, reconstruct_u16
 
@@ -777,19 +776,17 @@ class Layout:
     windows (TRACE Mechanism I).  ``uses_codec`` marks layouts whose
     payloads go through the inline codec (drives bypass-rate accounting).
 
-    Encoding is batched two ways: :meth:`encode_batch` is the production
-    path — a whole flush group in a few vectorized passes (one plane pack,
-    one ``compress_batch`` over every payload stream) — while
-    :meth:`encode_batch_scalar` is the O(blocks x planes) per-block
-    reference the device originally shipped with.  Both must produce
-    byte-identical payloads, flags and KV metas; the encode differential
-    tests hold them to that.
+    :meth:`encode_batch` encodes a whole flush group in a few vectorized
+    passes (one plane pack, one ``codec.compress_slab`` over every payload
+    stream).  Its payloads, flags and KV metas must equal a per-block
+    encode (per-window transform, per-block pack, per-stream codec) byte
+    for byte; the encode tests hold it to the reference they keep.
 
-    Both take the group's blocks as ``chunks`` and, on a ``kv_transform``
+    It takes the group's blocks as ``chunks`` and, on a ``kv_transform``
     layout, ``kv_windows``: entry ``i`` is either a ready block
     (``chunks[i]``) or an untransformed ``(n, C)`` KV window
     (``kv_windows[i]``, ``chunks[i]`` None), which the layout transforms
-    and encodes as one block.  Each returns one ``(payloads, flags,
+    and encodes as one block.  It returns one ``(payloads, flags,
     kv_meta)`` per entry, ``kv_meta`` None for a ready block.
     """
 
@@ -801,13 +798,6 @@ class Layout:
     def encode_batch(self, chunks: Sequence[Optional[np.ndarray]], codec: str,
                      kv_windows: Optional[Sequence] = None) -> List[Encoded]:
         """Vectorized batch encode."""
-        raise NotImplementedError
-
-    def encode_batch_scalar(self, chunks: Sequence[Optional[np.ndarray]],
-                            codec: str,
-                            kv_windows: Optional[Sequence] = None
-                            ) -> List[Encoded]:
-        """Per-block reference encode (parity oracle + benchmark baseline)."""
         raise NotImplementedError
 
     def fetched_payloads(self, block: _Block, view: PrecisionView) -> Sequence[int]:
@@ -833,20 +823,14 @@ class WordLayout(Layout):
 
     def encode_batch(self, chunks, codec, kv_windows=None):
         raws = [chunk.tobytes() for chunk in chunks]
-        if self.compress:
-            payloads, flags = codecs.compress_batch(raws, codec)
-            return [([pay], [fl], None) for pay, fl in zip(payloads, flags)]
-        return [([raw], [codecs.RAW], None) for raw in raws]
-
-    def encode_batch_scalar(self, chunks, codec, kv_windows=None):
-        out = []
-        for chunk in chunks:
-            raw = chunk.tobytes()
-            if self.compress:
-                out.append(codecs.compress_block(raw, codec))
-            else:
-                out.append((raw, codecs.RAW))
-        return [([pay], [fl], None) for pay, fl in out]
+        if not self.compress:
+            return [([raw], [codecs.RAW], None) for raw in raws]
+        sizes = np.asarray([len(raw) for raw in raws], dtype=np.int64)
+        ends = np.cumsum(sizes)
+        payloads, flags = codecs.compress_slab(
+            np.frombuffer(b"".join(raws), dtype=np.uint8), ends - sizes,
+            ends, codec)
+        return [([pay], [fl], None) for pay, fl in zip(payloads, flags)]
 
     def fetched_payloads(self, block, view):
         return (0,)
@@ -938,8 +922,10 @@ class BitplaneLayout(Layout):
         Blocks are padded to a byte multiple, so their plane streams
         concatenate cleanly: packing the concatenation and slicing per
         block is byte-identical to packing each block alone.  The packed
-        plane matrix is handed to the codec as a flat slab with (start,
-        end) stream bounds — no per-stream bytes are materialized.
+        plane matrix is handed to the codec's one encoder,
+        ``codec.compress_slab``, as a flat slab with (start, end) stream
+        bounds — no per-stream bytes are materialized.  The encode tests
+        hold the result to a per-block pack and codec.
         """
         from ..kernels import dispatch
         from ..kernels.bitplane import kv_pack_planes_slab, pack_planes_slab
@@ -984,28 +970,6 @@ class BitplaneLayout(Layout):
              metas[i])
             for i in range(nblk)
         ]
-
-    def encode_batch_scalar(self, chunks, codec, kv_windows=None):
-        # The original write pipeline: per-window KV transform, per-block
-        # plane pack, per-plane compress_block — O(blocks x planes)
-        # python-level calls.
-        out = []
-        chunks, metas = list(chunks), [None] * len(chunks)
-        for i, w in enumerate(kv_windows or ()):
-            if w is not None:
-                stream, metas[i] = kv_forward(w)
-                chunks[i] = _pad8(stream)
-                obs.count("tier.kv_host_windows")
-        self._check_sizes(chunks)
-        for chunk, meta in zip(chunks, metas):
-            planes = pack_planes(chunk.ravel())
-            payloads, flags = [], []
-            for p in range(BF16_BITS):
-                pay, fl = codecs.compress_block(planes[p].tobytes(), codec)
-                payloads.append(pay)
-                flags.append(fl)
-            out.append((payloads, flags, meta))
-        return out
 
     def fetched_payloads(self, block, view):
         return view.fetched_planes()
@@ -1178,7 +1142,6 @@ class TierStore:
                  codec: str = "lz4", block_elems: int = BLOCK_ELEMS,
                  index_cache_entries: int = 4096, kv_window: int = 64,
                  link_model: LinkModel = LinkModel(), window: int = 64,
-                 batched_encode: bool = True,
                  sanitize: Optional[bool] = None, device_id: int = 0):
         self.layout = LAYOUTS[layout]() if isinstance(layout, str) else layout
         self.codec = codecs.resolve_codec(codec)
@@ -1187,7 +1150,6 @@ class TierStore:
         self.link_model = link_model
         self.device_id = device_id           # fleet position (receipts carry it)
         self.window = window                 # max queued (in-flight) reads
-        self.batched_encode = batched_encode  # False: scalar reference path
         # Runtime invariant sanitizer: explicit flag wins, else the
         # TRACE_SANITIZE env var ("" / "0" = off).  See _Sanitizer.
         if sanitize is None:
@@ -1509,8 +1471,8 @@ class TierStore:
         encodes in a few vectorized passes (``encode_batch``), mirroring
         how queued reads share one decode slab.  Block commit order — and
         therefore payload bytes, receipts and index entries — is identical
-        to encoding each request alone; the differential tests hold the
-        batched and scalar pipelines to byte-identity.
+        to encoding each request alone; the encode tests hold the stored
+        bytes to a per-block reference pipeline.
 
         Writes are *posted* (CXL.mem semantics): they occupy the pipes but
         the host does not wait, so their receipts carry schedule latency
@@ -1597,9 +1559,8 @@ class TierStore:
         if not slab.chunks:
             return
         with obs.span("tier.encode"):
-            enc = (self.layout.encode_batch if self.batched_encode
-                   else self.layout.encode_batch_scalar)
-            encoded = enc(slab.chunks, self.codec, slab.kv_windows)
+            encoded = self.layout.encode_batch(slab.chunks, self.codec,
+                                               slab.kv_windows)
             for (payloads, flags, meta), key, rec, chunk, valid in zip(
                     encoded, slab.keys, slab.recs, slab.chunks, slab.valids):
                 padded = chunk.size if chunk is not None else _pad8_size(valid)

@@ -3,8 +3,7 @@
 The encoder's hot path — match-table build, previous-occurrence
 resolution, LCP extension and greedy selection — restated as array
 programs over a whole flush group's concatenated (plane, block) streams,
-so the scalar python match loop the codec shipped with is no longer on
-the write path.  Four passes:
+so no per-byte python loop runs on the write path.  Four passes:
 
 1. **prep** — 4-byte little-endian words, multiplicative hashes and
    byte-run boundaries for every position.  On a TPU this is a pallas
@@ -15,7 +14,7 @@ the write path.  Four passes:
    keys, then same-key adjacency.  Candidates can never cross a stream
    boundary, exactly like the reference scan's per-block table.
 3. **candidate filter** — window / end-of-block / run-stride rules as
-   boolean masks (the reference rules in ``codec._lz4_events_scalar``).
+   boolean masks (the rules of the tests' per-block reference scan).
 4. **greedy select** — every stream keeps a cursor; one round advances
    ALL live streams by their next selected match (LCP resolved lazily:
    run-boundary table for offset-1 byte runs, word-gallop otherwise —
@@ -32,8 +31,8 @@ Dispatch goes through ``kernels.dispatch.choose``, like
 under one jit) runs on a TPU or under ``force="device"`` (interpret-mode
 pallas off the TPU — the equivalence tests); the numpy path runs
 anywhere else and is the CPU production encoder.  Both are
-byte-identical to the scalar reference — ``codec.lz4_compress_batch``
-differential-tests them against ``TRACE_SCALAR_LZ4=1``.
+byte-identical to the scalar per-block reference encoder that the tests
+keep (``tests/encode_ref.py``).
 """
 
 from __future__ import annotations
@@ -49,9 +48,10 @@ from jax.experimental import pallas as pl
 
 from . import dispatch
 
-# LZ4 block-format constants + repo match-policy knobs.  core.codec's
-# scalar reference mirrors these (asserted at dispatch time there): a
-# drift would silently break kernel-vs-oracle byte identity.
+# LZ4 block-format constants + repo match-policy knobs.  core.codec
+# mirrors these (asserted at dispatch time there) and the tests' scalar
+# reference encodes with core.codec's: a drift would silently break
+# kernel-vs-reference byte identity.
 HASH_LOG = 13
 HASH_SIZE = 1 << HASH_LOG
 MIN_MATCH = 4
@@ -72,8 +72,8 @@ def match_events_slab(slab, starts, ends,
     ``pack_planes_slab``); ``starts``/``ends`` bound each stream's
     half-open byte range, disjoint and ascending (gaps — bypassed
     streams — are allowed and never touched).  Returns ``(pos, dist, mlen)`` int64 arrays sorted by
-    global position: the matches a per-stream scalar
-    ``codec._lz4_events_scalar`` scan would select, bit for bit.
+    global position: the matches the tests' per-stream scalar reference
+    scan would select, bit for bit.
 
     ``force``: ``"numpy"`` pins the vectorized-numpy fallback,
     ``"device"`` pins the pallas+jnp path (interpret mode off the

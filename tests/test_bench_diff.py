@@ -41,10 +41,10 @@ def test_identical_rows_pass(tmp_path):
 
 
 def test_floor_fires_even_when_baseline_agrees(tmp_path):
-    """lz4_kernel_speedup < 2.0 fails even if the committed baseline is
+    """pnm_tok_s_gain_512k < 3.0 fails even if the committed baseline is
     just as bad — floors are PR acceptance, not drift detection."""
     fresh, base = _dirs(tmp_path)
-    rows = [("lz4_kernel_speedup", 1.5, "x")]
+    rows = [("pnm_tok_s_gain_512k", 1.5, "x")]
     _write(fresh, "m", rows)
     _write(base, "m", rows)
     assert _run(fresh, base) == 1
@@ -89,11 +89,11 @@ def test_track_only_suffix_never_gates(tmp_path):
 def test_update_seeds_baseline_but_enforces_floors(tmp_path):
     fresh, base = _dirs(tmp_path)
     _write(fresh, "m", [("encode_ms", 12.0, "ms"),
-                        ("lz4_kernel_speedup", 2.3, "x")])
+                        ("pnm_tok_s_gain_512k", 3.4, "x")])
     assert _run(fresh, base, "--update") == 0
     assert _run(fresh, base) == 0       # seeded baseline now gates cleanly
     # a floor-violating run must not become the new baseline
-    _write(fresh, "bad", [("lz4_kernel_speedup", 1.2, "x")])
+    _write(fresh, "bad", [("pnm_tok_s_gain_512k", 1.2, "x")])
     assert _run(fresh, base, "--update") == 1
     assert not os.path.exists(os.path.join(base, "BENCH_bad.json"))
 

@@ -1,18 +1,21 @@
-"""Batched encode pipeline: parity with the scalar path, bypass accounting.
+"""Batched encode pipeline: parity with the per-block reference, bypass
+accounting.
 
-The hard invariant of the write-path refactor: for every layout, the
-vectorized batched encoder (one plane pack + one compress_batch per encode
-slab, batched KV transform) produces byte-identical stored payloads, flags,
-index entries and receipts to the scalar O(blocks x planes) reference
-pipeline.  Also covers the codec-level batch primitives and the bypass
-pre-screen / threshold accounting.
+The hard invariant of the write path: for every layout, the vectorized
+encoder (one plane pack + one ``compress_slab`` per encode slab, batched
+KV transform) produces byte-identical stored payloads, flags, index
+entries and receipts to the per-block reference pipeline
+(``encode_ref.encode_blocks_ref``).  Also covers ``compress_slab`` against
+the per-block ``encode_ref.compress_block`` and the bypass pre-screen /
+threshold accounting.
 """
 
-import time
+import functools
 
 import numpy as np
 import pytest
 
+import encode_ref
 from repro import obs
 from repro.core import codec, synth
 from repro.core.bitplane import pack_planes
@@ -67,14 +70,15 @@ def _storage_state(dev):
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_batched_encode_byte_identical_to_scalar(layout):
+def test_batched_encode_byte_identical_to_scalar(layout, monkeypatch):
     """Stored bytes, index entries and receipts agree exactly between the
-    batched and scalar encoders, for sync and async write posting."""
+    layout's encoder and the per-block reference run through the same
+    store, for sync (reference) and async (production) write posting."""
     kv_window = 16
-    scalar_dev = TierStore(layout=layout, kv_window=kv_window,
-                           batched_encode=False)
-    batched_dev = TierStore(layout=layout, kv_window=kv_window,
-                            batched_encode=True)
+    scalar_dev = TierStore(layout=layout, kv_window=kv_window)
+    monkeypatch.setattr(scalar_dev.layout, "encode_batch", functools.partial(
+        encode_ref.encode_blocks_ref, scalar_dev.layout))
+    batched_dev = TierStore(layout=layout, kv_window=kv_window)
     batch = _mixed_write_batch(kv_window)
     s_recs = scalar_dev.submit(batch)
     b_recs = [t.wait() for t in batched_dev.submit_async(batch)]
@@ -107,7 +111,10 @@ def test_single_vs_multi_request_posting_identical(layout):
     assert _storage_state(one) == _storage_state(many)
 
 
-def test_compress_batch_matches_compress_block():
+@pytest.mark.parametrize("name", sorted(codec.CODECS))
+def test_compress_batch_matches_compress_block(name):
+    """``compress_slab`` stores what the per-block reference stores, under
+    every registered codec, and the payloads round-trip."""
     rng = np.random.default_rng(0)
     chunks = [
         bytes(rng.integers(0, 256, 4096, dtype=np.uint8)),   # incompressible
@@ -118,14 +125,37 @@ def test_compress_batch_matches_compress_block():
         bytes(np.tile(rng.integers(0, 256, 97).astype(np.uint8), 50)),
         (b"the quick brown fox jumps over the lazy dog. " * 120)[:4096],
     ]
-    for name in codec.CODECS:
-        pays, flags = codec.compress_batch(chunks, name)
-        for chunk, pay, fl in zip(chunks, pays, flags):
-            p2, f2 = codec.compress_block(chunk, name)
-            assert (pay, fl) == (p2, f2), name
-        outs = codec.decompress_batch(pays, flags, name,
-                                      [len(c) for c in chunks])
-        assert outs == chunks
+    pays, flags = codec.compress_slab(*encode_ref.slab_of(chunks), name)
+    assert list(zip(pays, flags)) == [
+        encode_ref.compress_block(c, name) for c in chunks]
+    assert codec.decompress_batch(pays, flags, name,
+                                  [len(c) for c in chunks]) == chunks
+
+
+def test_compress_slab_identical_to_reference_on_kv_flush(monkeypatch):
+    """The exact ``(slab, starts, ends)`` calls that a 64-window KV flush
+    through ``bitplane-kv`` makes store what the per-block reference
+    stores, stream for stream."""
+    calls = []
+    compress_slab = codec.compress_slab
+
+    def spy(slab, starts, ends, name, force=None):
+        out = compress_slab(slab, starts, ends, name, force)
+        calls.append((np.array(slab), np.array(starts), np.array(ends),
+                      name, out))
+        return out
+
+    monkeypatch.setattr(codec, "compress_slab", spy)
+    pages = synth.kv_cache(64 * 16, 128, seed=24).reshape(64, 16, 128)
+    TierStore(layout="bitplane-kv", kv_window=16).submit(
+        [WriteReq(f"p{i}", p, kind=KV) for i, p in enumerate(pages)])
+    assert calls and sum(c[1].size for c in calls) == 64 * 16
+    for slab, starts, ends, name, (pays, flags) in calls:
+        assert list(zip(pays, flags)) == [
+            encode_ref.compress_block(slab[s:e].tobytes(), name)
+            for s, e in zip(starts, ends)]
+    assert {codec.RAW, codec.COMPRESSED} <= {
+        f for c in calls for f in c[4][1]}
 
 
 def test_lz4_batch_identity_random_battery():
@@ -133,8 +163,8 @@ def test_lz4_batch_identity_random_battery():
     battery = [bytes(rng.integers(0, hi, n, dtype=np.uint8))
                for hi in (2, 4, 256)
                for n in (0, 1, 5, 12, 13, 127, 128, 255, 512, 4096)]
-    scalar = [codec.lz4_compress(c) for c in battery]
-    batched = codec.lz4_compress_batch(battery)
+    scalar = [encode_ref.lz4_compress(c) for c in battery]
+    batched = codec._lz4_slab_streams(*encode_ref.slab_of(battery))
     assert scalar == batched
     for data, comp in zip(battery, scalar):
         if data:
@@ -144,18 +174,23 @@ def test_lz4_batch_identity_random_battery():
 def test_prescreen_routes_incompressible_to_bypass():
     rng = np.random.default_rng(2)
     noise = bytes(rng.integers(0, 256, 2048, dtype=np.uint8))
-    assert codec.prescreen_bypass(noise)
-    pay, fl = codec.compress_block(noise, "lz4")
-    assert fl == codec.RAW and pay == noise
+    assert encode_ref.prescreen_bypass(noise)
+    assert encode_ref.compress_block(noise, "lz4") == (noise, codec.RAW)
     # compressible payloads must never be pre-screened away
-    for data in (b"\x00" * 2048, b"ab" * 1024,
-                 bytes(np.tile(rng.integers(0, 256, 256).astype(np.uint8),
-                               16))):
-        assert not codec.prescreen_bypass(data)
-        _, fl = codec.compress_block(data, "lz4")
+    compressible = (b"\x00" * 2048, b"ab" * 1024,
+                    bytes(np.tile(rng.integers(0, 256, 256).astype(np.uint8),
+                                  16)))
+    for data in compressible:
+        assert not encode_ref.prescreen_bypass(data)
+        _, fl = encode_ref.compress_block(data, "lz4")
         assert fl == codec.COMPRESSED
     # short blocks skip the screen entirely
-    assert not codec.prescreen_bypass(noise[:64])
+    assert not encode_ref.prescreen_bypass(noise[:64])
+    # compress_slab makes the same decisions over one slab
+    pays, flags = codec.compress_slab(
+        *encode_ref.slab_of((noise,) + compressible), "lz4")
+    assert pays[0] == noise
+    assert flags == [codec.RAW] + [codec.COMPRESSED] * len(compressible)
 
 
 def test_bypass_threshold_and_counters():
@@ -309,44 +344,19 @@ def test_kv_pack_device_path_stores_what_the_twin_stores(mixed, monkeypatch):
             b.data.ravel(), next(r.data for r in reqs if r.key == k).ravel())
 
 
-@pytest.mark.parametrize("force, batched, host, decisions", [
-    ("pallas", True, 0, {"kernel.kv_pack.interpret": 2}),
-    (None, True, 80, {"kernel.kv_pack.numpy": 2}),
-    (None, False, 80, {}),
-], ids=["device", "cpu", "scalar"])
-def test_kv_host_windows_counter(force, batched, host, decisions,
-                                 monkeypatch):
+@pytest.mark.parametrize("force, host, decisions", [
+    ("pallas", 0, {"kernel.kv_pack.interpret": 2}),
+    (None, 80, {"kernel.kv_pack.numpy": 2}),
+], ids=["device", "cpu"])
+def test_kv_host_windows_counter(force, host, decisions, monkeypatch):
     """``tier.kv_host_windows`` counts the windows transformed on the
     host; a slab of windows alone is one ``kv_pack`` decision."""
     pages = synth.kv_cache(80 * 16, 128, seed=23).reshape(80, 16, 128)
     _pin_kv_pack(monkeypatch, force)
-    dev = TierStore(layout="bitplane-kv", kv_window=16,
-                    batched_encode=batched)
+    dev = TierStore(layout="bitplane-kv", kv_window=16)
     obs.reset()
     dev.submit([WriteReq(f"p{i}", p, kind=KV) for i, p in enumerate(pages)])
     counters = obs.snapshot()["counters"]
     assert counters.get("tier.kv_host_windows", 0) == host
     assert {k: n for k, n in counters.items()
             if k.startswith("kernel.kv_pack.")} == decisions
-
-
-def test_batched_encode_faster_than_scalar():
-    """A serving-sized KV flush through the batched encoder must beat the
-    scalar O(blocks x planes) pipeline — the write-side mirror of
-    test_batched_kv_stream_read_faster_than_sequential.  Generous margin
-    (plain 'faster', not the benchmarked ~3x) keeps CI stable."""
-    data = [synth.kv_cache(32, 64, seed=200 + i) for i in range(24)]
-    reqs = [WriteReq(f"p{i}", d, kind=KV) for i, d in enumerate(data)]
-
-    def run(batched):
-        best = float("inf")
-        for _ in range(3):
-            dev = TierStore(layout="bitplane-kv", kv_window=32,
-                            batched_encode=batched)
-            t0 = time.perf_counter()
-            dev.submit(reqs)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t_scalar, t_batched = run(False), run(True)
-    assert t_batched < t_scalar, (t_batched, t_scalar)

@@ -2,11 +2,11 @@
 
 Four pillars:
 
-1. **byte identity** — the kernel path (``lz4_compress_batch`` →
+1. **byte identity** — the kernel path (``codec._lz4_slab_streams`` →
    ``kernels.lz4.match_events_slab`` + ``lz4_emit_events``) produces the
-   same bytes as the scalar per-block reference (``lz4_compress``) AND
-   the PR 3 fused slab oracle (``TRACE_SCALAR_LZ4=1``), on an
-   adversarial corpus and on hypothesis-generated batches;
+   same bytes as the scalar per-block reference
+   (``encode_ref.lz4_compress``), on an adversarial corpus and on
+   hypothesis-generated batches;
 2. **device parity** — the pallas+jnp path (``force="device"``,
    interpret mode on CPU) selects the exact events of the numpy path;
 3. **decode hardening** — truncated and bit-flipped frames raise the
@@ -23,6 +23,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import encode_ref
 from repro.core import codec
 from repro.kernels import lz4 as klz4
 
@@ -58,7 +59,11 @@ def _adversarial_corpus():
 
 
 def _scalar_oracle(chunks):
-    return [codec.lz4_compress(c) for c in chunks]
+    return [encode_ref.lz4_compress(c) for c in chunks]
+
+
+def _kernel_path(chunks):
+    return codec._lz4_slab_streams(*encode_ref.slab_of(chunks))
 
 
 # ---------------------------------------------------------------------------
@@ -68,24 +73,11 @@ def _scalar_oracle(chunks):
 def test_kernel_batch_identical_to_scalar_on_adversarial_corpus():
     chunks = _adversarial_corpus()
     scalar = _scalar_oracle(chunks)
-    assert codec.lz4_compress_batch(chunks) == scalar
+    assert _kernel_path(chunks) == scalar
     # every frame round-trips under the hardened decoder
     for data, comp in zip(chunks, scalar):
         if data:
             assert codec.lz4_decompress(comp, max_out=len(data)) == data
-
-
-def test_scalar_lz4_env_pins_oracle_with_identical_bytes(monkeypatch):
-    """``TRACE_SCALAR_LZ4=1`` swaps in the PR 3 fused slab encoder; the
-    bytes must not change — that is what makes it usable as a CI parity
-    oracle (kernels_bench asserts the same identity per run)."""
-    chunks = _adversarial_corpus()
-    kernel = codec.lz4_compress_batch(chunks)
-    monkeypatch.setenv("TRACE_SCALAR_LZ4", "1")
-    assert codec._scalar_lz4_forced()
-    assert codec.lz4_compress_batch(chunks) == kernel
-    monkeypatch.setenv("TRACE_SCALAR_LZ4", "0")
-    assert not codec._scalar_lz4_forced()
 
 
 if HAVE_HYPOTHESIS:
@@ -99,7 +91,7 @@ if HAVE_HYPOTHESIS:
         min_size=1, max_size=8))
     @settings(max_examples=40, deadline=None)
     def test_kernel_batch_identical_to_scalar_any_chunks(chunks):
-        assert codec.lz4_compress_batch(chunks) == _scalar_oracle(chunks)
+        assert _kernel_path(chunks) == _scalar_oracle(chunks)
 else:  # pragma: no cover - hypothesis is a pinned CI dep
     @pytest.mark.skip(reason="property test needs hypothesis")
     def test_kernel_batch_identical_to_scalar_any_chunks():
@@ -171,7 +163,7 @@ def test_decompress_truncated_frames_raise_structured_error():
     CorruptPayloadError or decodes to a prefix-consistent payload —
     never IndexError, never bytes past the original."""
     for data in _fuzz_corpus():
-        comp = codec.lz4_compress(data)
+        comp = encode_ref.lz4_compress(data)
         for cut in range(len(comp)):
             try:
                 out = codec.lz4_decompress(comp[:cut], max_out=len(data))
@@ -185,7 +177,7 @@ def test_decompress_bitflipped_frames_never_crash():
     error or decodes within the caller's bound — IndexError/OverflowError
     (the pre-hardening failure modes) are regressions."""
     for data in _fuzz_corpus():
-        comp = codec.lz4_compress(data)
+        comp = encode_ref.lz4_compress(data)
         stride = max(1, len(comp) // 128)   # cap work on long frames
         for i in range(0, len(comp), stride):
             for bit in (0x01, 0x80):

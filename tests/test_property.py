@@ -20,6 +20,7 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
+import encode_ref
 from repro.core import codec, synth
 from repro.core.bitplane import pack_planes, plane_bytes, unpack_planes
 from repro.core.kv_transform import (
@@ -72,7 +73,8 @@ def test_kv_transform_lossless_any_payload(block, beta_val):
 @given(st.binary(min_size=0, max_size=4096))
 @settings(max_examples=40, deadline=None)
 def test_lz4_roundtrip_any_bytes(data):
-    comp = codec.lz4_compress(data)
+    comp, = codec._lz4_slab_streams(*encode_ref.slab_of([data]))
+    assert comp == encode_ref.lz4_compress(data)
     out = codec.lz4_decompress(comp) if data else b""
     assert out == data
 
@@ -80,7 +82,9 @@ def test_lz4_roundtrip_any_bytes(data):
 @given(st.binary(min_size=64, max_size=1024))
 @settings(max_examples=20, deadline=None)
 def test_compress_block_bypass_never_expands(data):
-    payload, flag = codec.compress_block(data, "lz4")
+    (payload,), (flag,) = codec.compress_slab(*encode_ref.slab_of([data]),
+                                              "lz4")
+    assert (payload, flag) == encode_ref.compress_block(data, "lz4")
     assert len(payload) <= len(data)
     assert codec.decompress_block(payload, flag, "lz4", len(data)) == data
 
@@ -89,11 +93,11 @@ def test_compress_block_bypass_never_expands(data):
                 max_size=12))
 @settings(max_examples=30, deadline=None)
 def test_compress_batch_identical_to_scalar_any_chunks(chunks):
-    """The slab-vectorized batch encoder is byte-identical to per-block
+    """``compress_slab`` is byte-identical to per-block reference
     compression for ANY chunk mix — payloads, flags, and round-trip."""
-    pays, flags = codec.compress_batch(chunks, "lz4")
+    pays, flags = codec.compress_slab(*encode_ref.slab_of(chunks), "lz4")
     for chunk, pay, fl in zip(chunks, pays, flags):
-        assert (pay, fl) == codec.compress_block(chunk, "lz4")
+        assert (pay, fl) == encode_ref.compress_block(chunk, "lz4")
     assert codec.decompress_batch(pays, flags, "lz4",
                                   [len(c) for c in chunks]) == chunks
 
@@ -126,11 +130,11 @@ def encode_chunk_batches(draw, max_chunks=6):
 @settings(max_examples=25, deadline=None)
 def test_layout_encode_batch_identical_to_scalar(chunks, layout):
     """Layout-level parity over random chunk shapes/dtypes: the batched
-    encoder (one pack + one compress_batch) equals the per-block scalar
-    reference exactly, for every layout."""
+    encoder (one pack + one compress_slab) equals the per-block reference
+    exactly, for every layout."""
     lay = LAYOUTS[layout]()
     assert lay.encode_batch(chunks, "lz4") == \
-        lay.encode_batch_scalar(chunks, "lz4")
+        encode_ref.encode_blocks_ref(lay, chunks, "lz4")
 
 
 @given(u16_blocks(min_elems=64, max_elems=512, multiple_of=64))

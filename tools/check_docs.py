@@ -110,7 +110,7 @@ def build_symbol_tables():
                 for field in getattr(obj, "__dataclass_fields__", {}):
                     add(field, None)
             # constructor / function parameters are part of the documented
-            # surface (``page_tokens``, ``batched_encode``)
+            # surface (``page_tokens``, ``kv_window``)
             target = obj.__init__ if isinstance(obj, type) else obj
             if callable(target):
                 try:
@@ -144,9 +144,8 @@ def build_symbol_tables():
     strings.update(obs.SPAN_NAMES)            # program span and counter names
     strings.update(obs.COUNTER_NAMES)
     # bench rows gated by absolute floors (tools/bench_diff.py FLOORS)
-    strings.update(("lz4_kernel_speedup", "lz4_kernel_byte_identical",
-                    "encode_batched_speedup", "shard4_tok_s_gain",
-                    "pnm_tok_s_gain_512k", "pnm_topk_byte_identical"))
+    strings.update(("shard4_tok_s_gain", "pnm_tok_s_gain_512k",
+                    "pnm_topk_byte_identical"))
     strings.update(("ledger-stored-equality", "receipt-conservation",
                     "busy-clock-monotonic", "inflight-window-bound",
                     "retire-cleanup", "refcount-conservation"))
